@@ -135,9 +135,12 @@ def run_cascade(params: C.Params, cfg: C.CascadeConfig,
     # kernel's decomposition): the fused and unfused paths must agree not
     # just to tolerance but on every DISCRETE decision (ceil'd keep
     # counts, tie-breaks), which only holds if they run the same float
-    # ops in the same order.
+    # ops in the same order. The bias dot asks for HIGHEST precision: at
+    # DEFAULT a TPU rounds w_q to bf16 (~2e-3 relative), which would shift
+    # every item's lp by a query-wide offset the kernels cannot undo.
     w_eff = params["w_x"] * jnp.asarray(cfg.masks, jnp.float32)
-    zq = q @ params["w_q"].T + params["b"]
+    zq = (jnp.matmul(q, params["w_q"].T, precision=jax.lax.Precision.HIGHEST)
+          + params["b"])
     if plan.fused_filter:
         out = K.cascade_filter(x, w_eff, zq, mask, m_q, interpret=interpret)
         lp, surv = out["lp"], out["survivors"]

@@ -34,7 +34,6 @@ from typing import Callable, Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, PartitionSpec as PS
 
@@ -278,13 +277,13 @@ def _make_epoch_fn(cfg: C.CascadeConfig, lcfg: L.LossConfig, loss_fn,
     if mesh is None:
         return jax.jit(epoch, donate_argnums=(0, 1))
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         epoch, mesh=mesh,
         # theta/opt_state replicated, the packed log replicated, the
         # per-step minibatch group axis sharded over the data axis.
         in_specs=(PS(), PS(), PS(), PS(), PS(None, "data")),
         out_specs=(PS(), PS(), PS()),
-        check_rep=False)       # pmean'd grads make the outputs replicated
+        check_vma=False)       # pmean'd grads make the outputs replicated
     return jax.jit(sharded, donate_argnums=(0, 1))
 
 

@@ -38,7 +38,11 @@ must land on lanes for both the score matmul and the (G, G) rank
 matrices — G is padded to the 128-lane width, features to sublanes
 via the shared d-pad. The stage axis (T <= 8) stays resident as the
 minor dim of a (G, T_pad) accumulator; keep counts and expected
-counts are (1, T_pad) row vectors broadcast against it. Worst case
+counts are (1, T_pad) row vectors broadcast against it. Every per-group
+row (bias, mask, m_q, counts, keep counts) travels as a (B, 1, ·) array
+in (1, 1, ·) blocks, the block shape Mosaic accepts at any B (see
+cascade_score/kernel.py); the stage prefix sum is `stage_cumsum` and the
+score dot runs at HIGHEST precision, as in the scorer. Worst case
 per block at G = 512: a 512x128 f32 feature tile (256 KiB) plus three
 512x512 f32 rank temporaries (3 MiB) — comfortably inside the ~16 MiB
 VMEM.
@@ -52,8 +56,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANE = 128          # lane width: group axis padded to this
-MAX_STAGES = 8      # stage axis padded to the sublane width
+from repro.kernels.cascade_score.kernel import (HIGHEST, LANE, MAX_STAGES,
+                                                stage_cumsum)
+
 MAX_GROUP = 512     # one group per block; (G, G) temps cap the block size
 
 
@@ -61,21 +66,21 @@ def _kernel(x_ref, w_ref, zq_ref, mask_ref, mq_ref,
             lp_ref, surv_ref, counts_ref, nkeep_ref, *, t: int, g_cap: int):
     """Per-group fused score + Eq-10 keep counts + chained rank-select.
 
-    x: (1, G_pad, d_pad), w: (T_pad, d_pad), zq: (1, T_pad),
-    mask: (1, G_pad), mq: (1, 1) ->
-    lp/surv: (1, G_pad, T_pad), counts/nkeep: (1, T_pad).
+    x: (1, G_pad, d_pad), w: (T_pad, d_pad), zq: (1, 1, T_pad),
+    mask: (1, 1, G_pad), mq: (1, 1, 1) ->
+    lp/surv: (1, G_pad, T_pad), counts/nkeep: (1, 1, T_pad).
     """
     x = x_ref[0].astype(jnp.float32)                    # (G_pad, d_pad)
     w = w_ref[...].astype(jnp.float32)                  # (T_pad, d_pad)
-    zq = zq_ref[...].astype(jnp.float32)                # (1, T_pad)
-    valid = mask_ref[...].astype(jnp.float32)[0]        # (G_pad,)
-    m_q = mq_ref[0, 0].astype(jnp.float32)
+    zq = zq_ref[0].astype(jnp.float32)                  # (1, T_pad)
+    valid = mask_ref[0].astype(jnp.float32)[0]          # (G_pad,)
+    m_q = mq_ref[0].astype(jnp.float32)[0, 0]
 
     # -- fused scorer (same math as cascade_score): one MXU matmul ---------
     logits = jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
+        x, w, (((1,), (1,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32) + zq        # (G_pad, T_pad)
-    lp = jnp.cumsum(jax.nn.log_sigmoid(logits), axis=-1)
+    lp = stage_cumsum(jax.nn.log_sigmoid(logits))
     lp_ref[0] = lp
 
     # -- Eq 10: expected counts -> per-stage keep counts (scalars/stage) ---
@@ -84,8 +89,8 @@ def _kernel(x_ref, w_ref, zq_ref, mask_ref, mq_ref,
     counts = (m_q / n_q) * jnp.sum(pp, axis=0)          # (T_pad,)
     n_keep = jnp.clip(jnp.ceil(counts * jnp.sum(valid) / jnp.maximum(m_q, 1.0)),
                       1.0, float(g_cap))
-    counts_ref[...] = counts[None, :]
-    nkeep_ref[...] = n_keep[None, :]
+    counts_ref[0] = counts[None, :]
+    nkeep_ref[0] = n_keep[None, :]
 
     # -- chained rank-select: stable descending rank vs broadcast keep -----
     g_pad = x.shape[0]
@@ -130,9 +135,9 @@ def cascade_filter(x: jax.Array, w_eff: jax.Array, zq: jax.Array,
     d_pad = (-d) % LANE
     xp = jnp.pad(x, ((0, 0), (0, g_pad), (0, d_pad)))
     wp = jnp.pad(w_eff, ((0, MAX_STAGES - t), (0, d_pad)))
-    zqp = jnp.pad(zq, ((0, 0), (0, MAX_STAGES - t)))
-    maskp = jnp.pad(mask.astype(jnp.float32), ((0, 0), (0, g_pad)))
-    mqp = m_q.astype(jnp.float32).reshape(b, 1)
+    zqp = jnp.pad(zq, ((0, 0), (0, MAX_STAGES - t)))[:, None, :]
+    maskp = jnp.pad(mask.astype(jnp.float32), ((0, 0), (0, g_pad)))[:, None, :]
+    mqp = m_q.astype(jnp.float32).reshape(b, 1, 1)
     gp = g + g_pad
     dp = d + d_pad
     lp, surv, counts, nkeep = pl.pallas_call(
@@ -141,27 +146,27 @@ def cascade_filter(x: jax.Array, w_eff: jax.Array, zq: jax.Array,
         in_specs=[
             pl.BlockSpec((1, gp, dp), lambda i: (i, 0, 0)),
             pl.BlockSpec((MAX_STAGES, dp), lambda i: (0, 0)),
-            pl.BlockSpec((1, MAX_STAGES), lambda i: (i, 0)),
-            pl.BlockSpec((1, gp), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, MAX_STAGES), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, gp), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, gp, MAX_STAGES), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, gp, MAX_STAGES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, MAX_STAGES), lambda i: (i, 0)),
-            pl.BlockSpec((1, MAX_STAGES), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, MAX_STAGES), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, MAX_STAGES), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, gp, MAX_STAGES), jnp.float32),
             jax.ShapeDtypeStruct((b, gp, MAX_STAGES), jnp.float32),
-            jax.ShapeDtypeStruct((b, MAX_STAGES), jnp.float32),
-            jax.ShapeDtypeStruct((b, MAX_STAGES), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, MAX_STAGES), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, MAX_STAGES), jnp.float32),
         ],
         interpret=interpret,
     )(xp, wp, zqp, maskp, mqp)
     return {
         "lp": lp[:, :g, :t],
         "survivors": surv[:, :g, :t],
-        "expected_counts": counts[:, :t],
-        "n_keep": nkeep[:, :t],
+        "expected_counts": counts[:, 0, :t],
+        "n_keep": nkeep[:, 0, :t],
     }

@@ -52,7 +52,9 @@ backward identically):
     T to MAX_STAGES.
   * per grid step (b, j): one (1, BLOCK_GROUP, d_pad) packed tile of group
     b, the full (MAX_STAGES, d_pad) weight block (resident across the whole
-    grid), and group b's (1, MAX_STAGES) bias row.
+    grid), and group b's bias row. Per-group rows (bias, ll/cnt partials
+    and their cotangents) travel as (B, 1, MAX_STAGES) arrays in
+    (1, 1, MAX_STAGES) blocks, as in the batched scorer.
   * padded items / stages / features are zero: every partial is weighted by
     mask, wgt*mask or cost_w (all zero on padded rows), so padded rows
     contribute nothing; padded stage columns are garbage and sliced off.
@@ -60,7 +62,7 @@ backward identically):
     blocks in their resident rows (init at j == 0, += after), exactly like
     the batched scorer backward accumulates dzq; the cost row
     (1, MAX_STAGES) accumulates across the WHOLE sequential grid like the
-    backward's dw block.
+    backward's dw block. Both grid axes are therefore "arbitrary".
   * backward: one pass recomputes the logits and fuses the dNLL/dcost/
     dcount cotangents into TWO logit-gradient streams — the main stream
     (NLL + cost, flowing to w_eff and zq) and the penalty stream (counts,
@@ -83,7 +85,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.cascade_score.kernel import LANE, MAX_STAGES, _block_group
+from repro.kernels.cascade_score.kernel import (ACCUMULATE_2D, HIGHEST, LANE,
+                                                MAX_STAGES, _block_group,
+                                                stage_cumsum)
 
 # Number of data columns packed after the d_x features: y, mask, wgt, cost_w.
 N_DATA_COLS = 4
@@ -116,7 +120,7 @@ def _pad_loss(xc, w_eff, zq):
     bg = _block_group(g)
     xp = jnp.pad(xc, ((0, 0), (0, (-g) % bg), (0, (-dc) % LANE)))
     wp = jnp.pad(w_eff, ((0, MAX_STAGES - t), (0, xp.shape[2] - d)))
-    zqp = jnp.pad(zq, ((0, 0), (0, MAX_STAGES - t)))
+    zqp = jnp.pad(zq, ((0, 0), (0, MAX_STAGES - t)))[:, None, :]
     return xp, wp, zqp, bg
 
 
@@ -126,8 +130,9 @@ def _lp_and_cols(xc, w, zq, d_x):
     xf = xc.astype(jnp.float32)
     logits = jax.lax.dot_general(
         xf, w.astype(jnp.float32), (((1,), (1,)), ((), ())),
+        precision=HIGHEST,
         preferred_element_type=jnp.float32) + zq.astype(jnp.float32)
-    lp = jnp.cumsum(jax.nn.log_sigmoid(logits), axis=-1)     # (BG, T_pad)
+    lp = stage_cumsum(jax.nn.log_sigmoid(logits))            # (BG, T_pad)
     y = xf[:, d_x:d_x + 1]
     mask = xf[:, d_x + 1:d_x + 2]
     wgt = xf[:, d_x + 2:d_x + 3]
@@ -136,21 +141,21 @@ def _lp_and_cols(xc, w, zq, d_x):
 
 
 def _loss_kernel(d_x, t, xc_ref, w_ref, zq_ref, ll_ref, cost_ref, cnt_ref):
-    """xc: (1, BG, d_pad), w: (T_pad, d_pad), zq: (1, T_pad) ->
-    (1, T_pad) partial rows: ll/cnt accumulated over group b's item blocks
-    (the scalar NLL partial is broadcast across its row's lanes), cost
-    accumulated across the whole grid."""
+    """xc: (1, BG, d_pad), w: (T_pad, d_pad), zq: (1, 1, T_pad) ->
+    partial rows: ll/cnt (1, 1, T_pad) accumulated over group b's item
+    blocks (the scalar NLL partial is broadcast across its row's lanes),
+    cost (1, T_pad) accumulated across the whole grid."""
     i = pl.program_id(0)
     j = pl.program_id(1)
     _, lp, y, mask, wgt, cost_w = _lp_and_cols(
-        xc_ref[0], w_ref[...], zq_ref[...], d_x)
+        xc_ref[0], w_ref[...], zq_ref[0], d_x)
     lpc = jnp.minimum(lp[:, t - 1:t], LOG_P_CLAMP)           # (BG, 1)
     ll = (wgt * mask) * (y * lpc + (1.0 - y) * jnp.log1p(-jnp.exp(lpc)))
     pp = jnp.exp(lp)
     ll_blk = jnp.broadcast_to(ll.sum(axis=0, keepdims=True),
-                              (1, MAX_STAGES))               # (1, T_pad)
-    cost_blk = (pp * cost_w).sum(axis=0, keepdims=True)
-    cnt_blk = (pp * mask).sum(axis=0, keepdims=True)
+                              (1, MAX_STAGES))[None]         # (1, 1, T_pad)
+    cost_blk = (pp * cost_w).sum(axis=0, keepdims=True)      # (1, T_pad)
+    cnt_blk = (pp * mask).sum(axis=0, keepdims=True)[None]   # (1, 1, T_pad)
 
     @pl.when(j == 0)
     def _init():
@@ -188,37 +193,39 @@ def cascade_loss(xc: jax.Array, w_eff: jax.Array, zq: jax.Array,
         in_specs=[
             pl.BlockSpec((1, bg, dp), lambda i, j: (i, j, 0)),
             pl.BlockSpec((MAX_STAGES, dp), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, MAX_STAGES), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, MAX_STAGES), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, MAX_STAGES), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, MAX_STAGES), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, MAX_STAGES), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, MAX_STAGES), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, MAX_STAGES), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, MAX_STAGES), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, MAX_STAGES), jnp.float32),
             jax.ShapeDtypeStruct((1, MAX_STAGES), jnp.float32),
-            jax.ShapeDtypeStruct((b, MAX_STAGES), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, MAX_STAGES), jnp.float32),
         ],
+        compiler_params=ACCUMULATE_2D,
         interpret=interpret,
     )(xp, wp, zqp)
-    return outs[0][:, 0], outs[1][0, :t], outs[2][:, :t]
+    return outs[0][:, 0, 0], outs[1][0, :t], outs[2][:, 0, :t]
 
 
 def _loss_bwd_kernel(d_x, t, xc_ref, w_ref, zq_ref, gll_ref, gcost_ref,
                      gcnt_ref, dxc_ref, dw_ref, dzq_ref, dzqp_ref):
     """One recompute pass fusing the three cotangent streams — see the
-    module docstring. g*: (1, T_pad) cotangent rows (gll: per-group scalar
-    broadcast across lanes, only stage t-1 taps it; gcost: the one global
-    Eq-8 row, resident across the whole grid; gcnt: per-group)."""
+    module docstring. Cotangent rows: gll (1, 1, T_pad), a per-group
+    scalar broadcast across lanes that only stage t-1 taps; gcost
+    (1, T_pad), the one global Eq-8 row, resident across the whole grid;
+    gcnt (1, 1, T_pad), per group."""
     i = pl.program_id(0)
     j = pl.program_id(1)
     w = w_ref[...].astype(jnp.float32)
     logits, lp, y, mask, wgt, cost_w = _lp_and_cols(
-        xc_ref[0], w, zq_ref[...], d_x)
-    gll = gll_ref[...].astype(jnp.float32)                   # (1, T_pad)
+        xc_ref[0], w, zq_ref[0], d_x)
+    gll = gll_ref[0].astype(jnp.float32)                     # (1, T_pad)
     gcost = gcost_ref[...].astype(jnp.float32)
-    gcnt = gcnt_ref[...].astype(jnp.float32)
+    gcnt = gcnt_ref[0].astype(jnp.float32)
     pp = jnp.exp(lp)
     lpl = lp[:, t - 1:t]                                     # (BG, 1)
     ppc = jnp.exp(jnp.minimum(lpl, LOG_P_CLAMP))
@@ -234,19 +241,19 @@ def _loss_bwd_kernel(d_x, t, xc_ref, w_ref, zq_ref, gll_ref, gcost_ref,
 
     def back(g_lp):
         # reverse cumsum over stages: gc[:, k] = sum_{t>=k} g_lp[:, t]
-        gc = g_lp.sum(axis=-1, keepdims=True) - jnp.cumsum(g_lp, -1) + g_lp
-        return gc * sig
+        return stage_cumsum(g_lp, reverse=True) * sig
 
     gm = back(g_lp_main)                                     # (BG, T_pad)
     gp_ = back(g_lp_pen)
     dxc_ref[0] = jax.lax.dot_general(
-        gm + gp_, w, (((1,), (0,)), ((), ())),
+        gm + gp_, w, (((1,), (0,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)                  # (BG, d_pad)
     dw_blk = jax.lax.dot_general(
         gm, xc_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
+        precision=HIGHEST,
         preferred_element_type=jnp.float32)                  # (T_pad, d_pad)
-    dzq_blk = gm.sum(axis=0, keepdims=True)                  # (1, T_pad)
-    dzqp_blk = gp_.sum(axis=0, keepdims=True)
+    dzq_blk = gm.sum(axis=0, keepdims=True)[None]            # (1, 1, T_pad)
+    dzqp_blk = gp_.sum(axis=0, keepdims=True)[None]
 
     @pl.when((i == 0) & (j == 0))
     def _init_dw():
@@ -281,35 +288,36 @@ def cascade_loss_bwd(xc: jax.Array, w_eff: jax.Array, zq: jax.Array,
     t, d = w_eff.shape
     xp, wp, zqp, bg = _pad_loss(xc, w_eff, zq)
     gp_, dp = xp.shape[1], xp.shape[2]
-    gs = [jnp.broadcast_to(g_ll.astype(jnp.float32)[:, None],
-                           (b, MAX_STAGES)),
+    gs = [jnp.broadcast_to(g_ll.astype(jnp.float32)[:, None, None],
+                           (b, 1, MAX_STAGES)),
           jnp.pad(g_cost.astype(jnp.float32),
                   (0, MAX_STAGES - t)).reshape(1, MAX_STAGES),
           jnp.pad(g_cnt.astype(jnp.float32),
-                  ((0, 0), (0, MAX_STAGES - t)))]
+                  ((0, 0), (0, MAX_STAGES - t)))[:, None, :]]
     dxc, dw, dzq, dzqp = pl.pallas_call(
         functools.partial(_loss_bwd_kernel, d_x, t),
         grid=(b, gp_ // bg),
         in_specs=[
             pl.BlockSpec((1, bg, dp), lambda i, j: (i, j, 0)),
             pl.BlockSpec((MAX_STAGES, dp), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, MAX_STAGES), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, MAX_STAGES), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, MAX_STAGES), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, 1, MAX_STAGES), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, MAX_STAGES), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, MAX_STAGES), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, MAX_STAGES), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bg, dp), lambda i, j: (i, j, 0)),
             pl.BlockSpec((MAX_STAGES, dp), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, MAX_STAGES), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, MAX_STAGES), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, MAX_STAGES), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, 1, MAX_STAGES), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, gp_, dp), jnp.float32),
             jax.ShapeDtypeStruct((MAX_STAGES, dp), jnp.float32),
-            jax.ShapeDtypeStruct((b, MAX_STAGES), jnp.float32),
-            jax.ShapeDtypeStruct((b, MAX_STAGES), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, MAX_STAGES), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, MAX_STAGES), jnp.float32),
         ],
+        compiler_params=ACCUMULATE_2D,
         interpret=interpret,
     )(xp, wp, zqp, *gs)
-    return dxc[:, :g, :dc], dw[:t, :d], dzq[:, :t], dzqp[:, :t]
+    return dxc[:, :g, :dc], dw[:t, :d], dzq[:, 0, :t], dzqp[:, 0, :t]

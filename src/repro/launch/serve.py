@@ -42,6 +42,7 @@ from repro.core import cascade as C
 from repro.core import losses as L
 from repro.core import trainer as T
 from repro.data import LogConfig, generate_log
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import replica_devices
 from repro.serving.batching import RankRequest
 from repro.serving.cascade_server import NeuralScorer
@@ -201,6 +202,7 @@ def main() -> None:
                          "warmup manifest instead of training — the first "
                          "live request must hit zero recompiles (enforced)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     serve_dir = args.serve_dir or None
     if args.warm_restart and not serve_dir:
@@ -388,6 +390,13 @@ def main() -> None:
     print("[serve] all futures resolved (zero dropped; "
           "submitted = completed + shed + errors"
           + (" globally across replicas)" if router_stats else ")"))
+    # Only the chaos legs may end in errors: without injected faults an
+    # error is a real executor failure (a Mosaic compile error, a device
+    # fault on a shape warmup missed), and exit 0 would hide it.
+    if st["errors"] and not (args.faults > 0 or args.kill_replica):
+        raise SystemExit(
+            f"[serve] FAIL: {st['errors']} request(s) ended in status "
+            "'error' with no faults injected")
 
     # The warm-restart contract: every compilation the serve phase needed
     # existed before the first live request. Measured as the jit-cache

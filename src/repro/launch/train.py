@@ -31,6 +31,7 @@ from repro.core import baselines as B
 from repro.core import losses as L
 from repro.core import trainer as T
 from repro.data import LogConfig, generate_log
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def params_digest(params) -> str:
@@ -149,6 +150,7 @@ def main() -> None:
     ap.add_argument("--crash-after-epoch", type=int, default=None,
                     help="test seam: hard-exit (code 9) after N epochs")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.target == "cloes":
         train_cloes(args)
     else:

@@ -1,0 +1,18 @@
+"""Kernels cascade_loss forward and backward: the least time the window's
+required training work takes at the chip's peaks (bench/workcount.py), as
+a share of the two loss kernels' device time in the trace. Nothing when
+the kernels are absent."""
+
+import workcount
+
+KERNEL = "cascade_loss"
+
+
+def read(facts):
+    trace = facts.get("trace")
+    if trace is None or facts.get("work") is None:
+        return None
+    seconds = trace.ops_matching(KERNEL)
+    if seconds <= 0:
+        return None
+    return 100.0 * workcount.least_seconds(facts["work"], facts["peaks"]) / seconds

@@ -163,6 +163,7 @@ def cascade_filter(x: jax.Array, w_eff: jax.Array, zq: jax.Array,
             jax.ShapeDtypeStruct((b, 1, MAX_STAGES), jnp.float32),
         ],
         interpret=interpret,
+        name="cascade_filter",
     )(xp, wp, zqp, maskp, mqp)
     return {
         "lp": lp[:, :g, :t],

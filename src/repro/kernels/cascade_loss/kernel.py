@@ -207,6 +207,7 @@ def cascade_loss(xc: jax.Array, w_eff: jax.Array, zq: jax.Array,
         ],
         compiler_params=ACCUMULATE_2D,
         interpret=interpret,
+        name="cascade_loss",
     )(xp, wp, zqp)
     return outs[0][:, 0, 0], outs[1][0, :t], outs[2][:, 0, :t]
 
@@ -319,5 +320,6 @@ def cascade_loss_bwd(xc: jax.Array, w_eff: jax.Array, zq: jax.Array,
         ],
         compiler_params=ACCUMULATE_2D,
         interpret=interpret,
+        name="cascade_loss_bwd",
     )(xp, wp, zqp, *gs)
     return dxc[:, :g, :dc], dw[:t, :d], dzq[:, 0, :t], dzqp[:, 0, :t]

@@ -296,6 +296,7 @@ def cascade_score_batched(x: jax.Array, w_eff: jax.Array, zq: jax.Array,
         out_specs=pl.BlockSpec((1, bg, MAX_STAGES), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, gp, MAX_STAGES), jnp.float32),
         interpret=interpret,
+        name="cascade_score_batched",
     )(xp, wp, zqp)
     return out[:, :g, :t]
 
@@ -376,6 +377,7 @@ def cascade_score_batched_bwd(x: jax.Array, w_eff: jax.Array, zq: jax.Array,
         ],
         compiler_params=ACCUMULATE_2D,
         interpret=interpret,
+        name="cascade_score_batched_bwd",
     )(xp, wp, zqp, gct)
     return dx[:, :g_items, :d], dw[:t, :d], dzq[:, 0, :t]
 

@@ -13,6 +13,11 @@ Two clocks, one lifecycle:
     drives everything. The soak contract: zero unresolved futures across
     pump shutdown.
 
+--spans records the spans of every flush (serving/spans.py: claim, pack,
+dispatch, fetch, resolve, and the pump's whole cycle) and reports each
+one's p50/p99 per flush, with the pumps' flush fill (served rows over
+padded rows).
+
 Request generation is timed SEPARATELY from the serve phase — the old
 closed-loop launcher started its clock before the submit loop, charging
 request construction to the server's QPS.
@@ -20,7 +25,7 @@ request construction to the server's QPS.
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --requests 500 --qps 400 \
       [--pump [--threads 4]] [--deadline-ms 130] [--max-queue 128] \
-      [--neural ARCH] [--report BENCH_serve.json]
+      [--neural ARCH] [--spans] [--report BENCH_serve.json]
 """
 
 from __future__ import annotations
@@ -52,6 +57,8 @@ from repro.serving.pump import SessionPump, run_wall_clock
 from repro.serving.router import ReplicaRouter, RouterConfig, make_replicas
 from repro.serving.session import (CascadeSession, DegradePolicy,
                                    FlushPolicy, ServingConfig)
+from repro.serving.spans import SpanRecorder
+from repro.serving.spans import report as span_report
 
 
 def build_serving_config(*, plan="filter", max_queue=128,
@@ -116,6 +123,15 @@ def compiled_count(sessions) -> int:
         fns[id(s._rank)] = s._rank
         fns[id(s._rank_noneural)] = s._rank_noneural
     return sum(f._cache_size() for f in fns.values())
+
+
+def flush_fill(pump_stats: list[dict]) -> float | None:
+    """Percent of the pumps' padded batch rows that carried a request
+    (None without a pump: the DES counts no padded rows)."""
+    rows = sum(p["rows_padded"] for p in pump_stats)
+    if not rows:
+        return None
+    return 100.0 * sum(p["served"] for p in pump_stats) / rows
 
 
 def save_serving_state(serve_dir: str, ses: CascadeSession) -> None:
@@ -191,6 +207,9 @@ def main() -> None:
                     help="arch id for the neural final stage (smoke variant)")
     ap.add_argument("--beta", type=float, default=5.0)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--spans", action="store_true",
+                    help="record each flush's spans and report their "
+                         "p50/p99 and the flush fill")
     ap.add_argument("--report", default="",
                     help="write the latency/lifecycle report as JSON here")
     ap.add_argument("--serve-dir", default="",
@@ -282,6 +301,9 @@ def main() -> None:
         print(f"[serve] warmed {len(shapes)} shape buckets in "
               f"{warmup_s:.1f}s")
     compiled_after_warmup = compiled_count(sessions)
+    if args.spans:
+        for s in sessions:
+            s.spans = SpanRecorder(enabled=True)
 
     # -- request generation, timed on its own (NOT charged to the server) --
     rng = np.random.default_rng(args.seed)
@@ -371,6 +393,21 @@ def main() -> None:
         # a still-live pump thread cannot tear the counters mid-read
         session_stats = ses.stats_export()
     print(f"[serve] session stats: {session_stats}")
+    spans = None
+    if args.spans:
+        spans = span_report([s.spans for s in sessions])
+        pumped = ([pump_stats] if pump_stats is not None
+                  else router_stats["replicas"] if args.pump and router_stats
+                  else [])
+        spans["flush_fill"] = flush_fill(pumped)
+        for name, row in spans["spans"].items():
+            print(f"[serve] span {name}: p50 {row['p50_ms']:.3f}ms p99 "
+                  f"{row['p99_ms']:.3f}ms over {row['flushes']} flushes")
+        fill = spans["flush_fill"]
+        print("[serve] flush fill: "
+              + (f"{fill:.1f}% of padded rows served" if fill is not None
+                 else "not counted (no pump)")
+              + f"; spans dropped {spans['dropped']}")
 
     if res.unresolved or unresolved_after_close:
         raise SystemExit(
@@ -439,6 +476,8 @@ def main() -> None:
             report["pump_stats"] = pump_stats
         if router_stats is not None:
             report["router_stats"] = router_stats
+        if spans is not None:
+            report["spans"] = spans
         with open(args.report, "w") as f:
             json.dump(report, f, indent=2)
         print(f"[serve] wrote {args.report}")

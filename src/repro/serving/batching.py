@@ -47,6 +47,8 @@ class RankResponse:
     error: str | None = None    # status="error": why service failed
     attempts: int = 1           # execute attempts spent on this request's
     # chunk (>1 means retries/bisection happened on its path)
+    flush_id: int | None = None  # the flush that served it: its spans'
+    # id (serving/spans.py); None when no flush did (shed at admission)
 
 
 def bucket_of(n_items: int, buckets: tuple[int, ...]) -> int:

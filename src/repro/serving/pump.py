@@ -44,10 +44,11 @@ import numpy as np
 
 from repro.serving.batching import RankRequest
 from repro.serving.session import CascadeSession, FlushChunk, RankFuture
+from repro.serving.spans import CYCLE
 
 
 def _monotonic_ms() -> float:
-    return time.monotonic() * 1e3
+    return time.monotonic_ns() / 1e6
 
 
 class SessionPump:
@@ -69,8 +70,11 @@ class SessionPump:
         # open (claimed, still-staging) chunk per bucket: submit() slots
         # late arrivals into these — guarded by session.lock
         self._open: dict[int, FlushChunk] = {}
-        self.stats = {"cycles": 0, "served": 0, "slot_joins": 0,
-                      "shutdown_shed": 0, "cycle_errors": 0, "restarts": 0}
+        # rows_padded sums the claimed chunks' pow2-padded capacity:
+        # served / rows_padded is how full the flushes ran
+        self.stats = {"cycles": 0, "served": 0, "rows_padded": 0,
+                      "slot_joins": 0, "shutdown_shed": 0,
+                      "cycle_errors": 0, "restarts": 0}
         self._thread = threading.Thread(target=self._run, name=name,
                                         daemon=True)
         # Supervision: chunk-level failures are contained inside
@@ -205,15 +209,20 @@ class SessionPump:
         keeps pumping; the finally block guarantees the open-chunk
         registration never leaks (a stale entry in self._open would
         swallow that bucket's slot-joins into a chunk nobody will ever
-        execute)."""
+        execute).
+
+        One clock read starts both the responses' service_ms and the
+        serve.cycle span, which runs on to the end of resolution."""
         ses = self.session
-        start = _monotonic_ms()
+        start_ns = time.monotonic_ns()
+        start = start_ns / 1e6
         chunk = ses.claim_due(claim_at)
         if chunk is None:
             return
         try:
             with ses.lock:
                 self.stats["cycles"] += 1
+                self.stats["rows_padded"] += chunk.capacity
                 if (len(chunk.entries) < chunk.capacity
                         and not self._closing):
                     chunk.open = True
@@ -244,6 +253,7 @@ class SessionPump:
                 chunk.open = False
                 if self._open.get(chunk.g) is chunk:
                     del self._open[chunk.g]
+            ses.spans.record(CYCLE, chunk.flush_id, start_ns)
 
     # -- supervision -------------------------------------------------------
 
@@ -266,11 +276,12 @@ class SessionPump:
                     self._thread.start()
 
     def stats_export(self) -> dict:
-        """Pump counters (cycles/served/slot_joins/shutdown_shed/
-        cycle_errors/restarts) plus the wrapped session's full metrics
-        surface (lifecycle, faults, pool allocated/reused). The pump
-        counters are copied under the session lock — every mutation site
-        holds it, so a live reporter cannot read a half-updated cycle."""
+        """Pump counters (cycles/served/rows_padded/slot_joins/
+        shutdown_shed/cycle_errors/restarts) plus the wrapped session's
+        full metrics surface (lifecycle, faults, pool allocated/reused).
+        The pump counters are copied under the session lock — every
+        mutation site holds it, so a live reporter cannot read a
+        half-updated cycle."""
         with self.session.lock:
             out = dict(self.stats)
         out["running"] = self.running

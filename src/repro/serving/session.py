@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import math
 import threading
 import time
@@ -55,6 +56,8 @@ from repro.serving.batching import (RankRequest, RankResponse,
                                     pack_into, padded_batch_rows,
                                     warmup_batch_sizes)
 from repro.serving.faults import CorruptOutput, FaultInjector
+from repro.serving.spans import (CLAIM, DISPATCH, FETCH, PACK, RESOLVE,
+                                 SpanRecorder)
 
 
 class QueueFull(RuntimeError):
@@ -217,13 +220,21 @@ class FlushChunk:
     virtual completion time through. `capacity` is the pow2-padded batch
     rows the packed buffer will carry — while `open` is True the pump may
     slot late arrivals into rows [len(entries), capacity): padding rows
-    the batch pays for anyway."""
+    the batch pays for anyway.
+
+    Under the pump a response's service_ms runs from the cycle's start,
+    before the claim, to the fetched results: claim, pack, dispatch and
+    fetch. It leaves out resolution (building the responses and setting
+    the futures), which the serve.resolve span measures. `flush_id`
+    names the flush in its responses and spans; bisection halves keep
+    their parent's."""
     g: int
     entries: list[_Pending]
     degrades: tuple[str, ...]       # flush-time degradations (chunk-wide)
     skip_neural: bool
     mq_scale: float
     capacity: int                   # padded batch rows (pow2 rule)
+    flush_id: int                   # per-session claim counter
     packed: int = 0                 # rows already staged into the buffer
     open: bool = False              # pump: accepting slot late-joins
     batch: dict | None = None       # pooled staging buffer once packed
@@ -267,7 +278,8 @@ class CascadeSession:
                  faults: FaultInjector | None = None,
                  name: str = "session",
                  device=None,
-                 pipeline_from: "CascadeSession | None" = None):
+                 pipeline_from: "CascadeSession | None" = None,
+                 spans: SpanRecorder | None = None):
         self.params = jax.tree_util.tree_map(jnp.asarray, params)
         self.cfg = cfg
         self.lcfg = lcfg or L.LossConfig()
@@ -356,12 +368,17 @@ class CascadeSession:
         # Backoff sleep, stubable in tests (and measured into virtual
         # service time by the DES, which times real wall around execute).
         self._sleep = time.sleep
+        # Spans of the flush steps (serving/spans.py; off by default) and
+        # the claim counter that names each flush in them.
+        self.spans = spans if spans is not None else SpanRecorder()
+        self._flush_ids = itertools.count(1)
 
     # -- the jitted pipeline ---------------------------------------------
 
     def _make_rank(self, with_neural: bool):
-        def impl(params: C.Params, x: jax.Array, q: jax.Array,
-                 mask: jax.Array, m_q: jax.Array) -> dict:
+        # The name is the program's in a trace (jit_cascade_rank).
+        def cascade_rank(params: C.Params, x: jax.Array, q: jax.Array,
+                         mask: jax.Array, m_q: jax.Array) -> dict:
             """Score -> hard filter -> latency estimate, end to end."""
             out = P.run_cascade(params, self.cfg, x, q, mask, m_q,
                                 fused=self.scfg.plan)
@@ -394,7 +411,7 @@ class CascadeSession:
             }
 
         donate = (3, 4) if self._donates else ()
-        return jax.jit(impl, donate_argnums=donate)
+        return jax.jit(cascade_rank, donate_argnums=donate)
 
     def rank_batch(self, batch: dict, *, skip_neural: bool = False) -> dict:
         """Run the jitted hard-cascade pipeline on a padded batch."""
@@ -678,7 +695,7 @@ class CascadeSession:
     def claim_due(self, now_ms: float) -> FlushChunk | None:
         """Dequeue the single most-urgent due chunk (None when nothing is
         due): earliest due time wins, ties go to the smaller bucket."""
-        with self.lock:
+        with self.spans.span(CLAIM) as span, self.lock:
             self._update_degrade()
             best_g, best_due = None, math.inf
             for g in self.buckets:
@@ -690,50 +707,60 @@ class CascadeSession:
                     best_g, best_due = g, due
             if best_g is None:
                 return None
-            return self.claim_bucket(best_g)
+            return self._claim(best_g, span)
 
     def claim_bucket(self, g: int) -> FlushChunk | None:
         """Dequeue one FIFO chunk from bucket g with the degradation
         decision frozen at claim time (the moment service is committed)."""
-        with self.lock:
-            self._update_degrade()
-            entries = self._pending[g][:self.scfg.batch_groups]
-            if not entries:
-                return None
-            del self._pending[g][:len(entries)]
-            # pending -> inflight under ONE lock hold: the atomic-snapshot
-            # identity (see stats init) must hold at every instant
-            self.stats["inflight"] += len(entries)
-            degrades: tuple[str, ...] = ()
-            skip_neural = False
-            mq_scale = 1.0
-            if self.degraded:
-                deg = self.scfg.degrade
-                if deg.skip_neural and self.neural is not None:
-                    skip_neural = True
-                    degrades += (DEGRADE_SKIP_NEURAL,)
-                if deg.mq_scale < 1.0:
-                    mq_scale = deg.mq_scale
-                    degrades += (DEGRADE_TIGHTEN_MQ,)
-            return FlushChunk(
-                g=g, entries=entries, degrades=degrades,
-                skip_neural=skip_neural, mq_scale=mq_scale,
-                capacity=padded_batch_rows(len(entries),
-                                           self.scfg.batch_groups))
+        with self.spans.span(CLAIM) as span, self.lock:
+            return self._claim(g, span)
+
+    def _claim(self, g: int, span) -> FlushChunk | None:
+        """claim_bucket's body; the caller holds the lock inside its
+        serve.claim span, which takes the new flush's id."""
+        self._update_degrade()
+        entries = self._pending[g][:self.scfg.batch_groups]
+        if not entries:
+            return None
+        del self._pending[g][:len(entries)]
+        # pending -> inflight under ONE lock hold: the atomic-snapshot
+        # identity (see stats init) must hold at every instant
+        self.stats["inflight"] += len(entries)
+        degrades: tuple[str, ...] = ()
+        skip_neural = False
+        mq_scale = 1.0
+        if self.degraded:
+            deg = self.scfg.degrade
+            if deg.skip_neural and self.neural is not None:
+                skip_neural = True
+                degrades += (DEGRADE_SKIP_NEURAL,)
+            if deg.mq_scale < 1.0:
+                mq_scale = deg.mq_scale
+                degrades += (DEGRADE_TIGHTEN_MQ,)
+        span.flush_id = flush_id = next(self._flush_ids)
+        return FlushChunk(
+            g=g, entries=entries, degrades=degrades,
+            skip_neural=skip_neural, mq_scale=mq_scale,
+            capacity=padded_batch_rows(len(entries),
+                                       self.scfg.batch_groups),
+            flush_id=flush_id)
 
     def pack_chunk(self, chunk: FlushChunk) -> None:
         """Stage any not-yet-packed entries into the chunk's pooled
         buffer. Incremental: the pump calls it once after claiming, and
         again after closing the chunk to stage slot late-joiners into the
         padding rows the batch already pays for."""
-        if chunk.batch is None:
-            chunk.batch = self.pool.acquire(chunk.capacity, chunk.g)
         n = len(chunk.entries)
-        if chunk.packed < n:
-            pack_into(chunk.batch,
-                      [e.req for e in chunk.entries[chunk.packed:n]],
-                      chunk.g, start=chunk.packed)
-            chunk.packed = n
+        if chunk.batch is not None and chunk.packed >= n:
+            return
+        with self.spans.span(PACK, chunk.flush_id):
+            if chunk.batch is None:
+                chunk.batch = self.pool.acquire(chunk.capacity, chunk.g)
+            if chunk.packed < n:
+                pack_into(chunk.batch,
+                          [e.req for e in chunk.entries[chunk.packed:n]],
+                          chunk.g, start=chunk.packed)
+                chunk.packed = n
 
     def execute_chunk(self, chunk: FlushChunk) -> dict:
         """Fault-tolerant execute: pack (if needed), run the jitted
@@ -765,17 +792,20 @@ class CascadeSession:
         if self.faults is not None:
             self.faults.on_attempt([e.req.request_id
                                     for e in chunk.entries])
-        res = self.rank_batch(batch, skip_neural=chunk.skip_neural)
-        scores = np.asarray(res["scores"])
-        if self.faults is not None:
-            scores = scores.copy()      # device fetches are read-only;
-            #                             the injector corrupts in place
-        out = {
-            "scores": scores,
-            "survivors": np.asarray(res["survivors"]),
-            "lat": np.asarray(res["est_latency_ms"]),
-            "stage_counts": np.asarray(res["stage_survivors"].sum(axis=1)),
-        }
+        with self.spans.span(DISPATCH, chunk.flush_id):
+            res = self.rank_batch(batch, skip_neural=chunk.skip_neural)
+        with self.spans.span(FETCH, chunk.flush_id):
+            scores = np.asarray(res["scores"])
+            if self.faults is not None:
+                scores = scores.copy()  # device fetches are read-only;
+                #                         the injector corrupts in place
+            out = {
+                "scores": scores,
+                "survivors": np.asarray(res["survivors"]),
+                "lat": np.asarray(res["est_latency_ms"]),
+                "stage_counts": np.asarray(
+                    res["stage_survivors"].sum(axis=1)),
+            }
         if self.faults is not None:
             self.faults.on_results(out, len(chunk.entries))
         return out
@@ -800,13 +830,15 @@ class CascadeSession:
 
     def _subchunk(self, chunk: FlushChunk, entries: list[_Pending]
                   ) -> FlushChunk:
-        """A bisection half: same bucket and degradation decision, its
-        own pow2-padded capacity (a warmed shape) and fresh buffer."""
+        """A bisection half: same bucket, degradation decision and flush
+        id, its own pow2-padded capacity (a warmed shape) and fresh
+        buffer."""
         return FlushChunk(
             g=chunk.g, entries=list(entries), degrades=chunk.degrades,
             skip_neural=chunk.skip_neural, mq_scale=chunk.mq_scale,
             capacity=padded_batch_rows(len(entries),
-                                       self.scfg.batch_groups))
+                                       self.scfg.batch_groups),
+            flush_id=chunk.flush_id)
 
     def _execute_with_retry(self, chunk: FlushChunk) -> dict:
         pol = self.scfg.retry
@@ -876,9 +908,19 @@ class CascadeSession:
         flush start (wait_ms accounting); done_ms is service COMPLETION —
         deadline_missed is decided there, so a chunk that starts before
         its deadline but finishes after is correctly reported late.
+        service_ms is done_ms - now_ms: it ends before this call, so the
+        time spent here (ordering each request's items, setting every
+        future under the lock) is in no response's stamps; the
+        serve.resolve span measures it.
         Explicit-clock callers that cannot know service time (step/flush)
         leave done_ms=None, collapsing completion onto the flush instant."""
-        done = now_ms if done_ms is None else done_ms
+        with self.spans.span(RESOLVE, chunk.flush_id):
+            return self._resolve_entries(chunk, results, now_ms,
+                                         now_ms if done_ms is None
+                                         else done_ms)
+
+    def _resolve_entries(self, chunk: FlushChunk, results: dict,
+                         now_ms: float, done: float) -> list[RankResponse]:
         scores, surv = results["scores"], results["survivors"]
         lat, stage_counts = results["lat"], results["stage_counts"]
         errors = results.get("error") or [None] * len(chunk.entries)
@@ -898,7 +940,7 @@ class CascadeSession:
                         degraded=degraded, truncated=e.truncated,
                         deadline_missed=missed,
                         wait_ms=now_ms - e.submit_ms,
-                        service_ms=done - now_ms)
+                        service_ms=done - now_ms, flush_id=chunk.flush_id)
                     e.future._resolve(resp)
                     self.stats["errors"] += 1
                     out.append(resp)
@@ -919,6 +961,7 @@ class CascadeSession:
                     wait_ms=now_ms - e.submit_ms,
                     service_ms=done - now_ms,
                     attempts=attempts[i],
+                    flush_id=chunk.flush_id,
                 )
                 e.future._resolve(resp)
                 self.stats["completed"] += 1
@@ -937,27 +980,28 @@ class CascadeSession:
         unresolved future of the claimed chunk with status="error" so
         the crash cannot hang a caller, and release the staging buffer.
         Already-resolved entries are left untouched."""
-        self._release_chunk(chunk)
-        done = now_ms if done_ms is None else done_ms
-        err = f"{type(error).__name__}: {error}"
-        out = []
-        with self.lock:
-            for e in chunk.entries:
-                if e.future.done():
-                    continue
-                self.stats["inflight"] -= 1
-                missed = (e.deadline_ms is not None
-                          and done > e.deadline_ms)
-                resp = _error_response(
-                    e.req, err, 1,
-                    degraded=e.degraded + chunk.degrades,
-                    truncated=e.truncated, deadline_missed=missed,
-                    wait_ms=now_ms - e.submit_ms,
-                    service_ms=done - now_ms)
-                e.future._resolve(resp)
-                self.stats["errors"] += 1
-                out.append(resp)
-        return out
+        with self.spans.span(RESOLVE, chunk.flush_id):
+            self._release_chunk(chunk)
+            done = now_ms if done_ms is None else done_ms
+            err = f"{type(error).__name__}: {error}"
+            out = []
+            with self.lock:
+                for e in chunk.entries:
+                    if e.future.done():
+                        continue
+                    self.stats["inflight"] -= 1
+                    missed = (e.deadline_ms is not None
+                              and done > e.deadline_ms)
+                    resp = _error_response(
+                        e.req, err, 1,
+                        degraded=e.degraded + chunk.degrades,
+                        truncated=e.truncated, deadline_missed=missed,
+                        wait_ms=now_ms - e.submit_ms,
+                        service_ms=done - now_ms, flush_id=chunk.flush_id)
+                    e.future._resolve(resp)
+                    self.stats["errors"] += 1
+                    out.append(resp)
+            return out
 
     # -- failover seams (serving.router) -----------------------------------
 

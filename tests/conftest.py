@@ -37,7 +37,7 @@ def pytest_configure(config):
 # through dynamic dispatch (depth_fn, injected clocks) the AST cannot see.
 _WITNESS_MODULES = {
     "test_session", "test_pump", "test_router", "test_faults",
-    "test_determinism", "test_serving_batching",
+    "test_determinism", "test_serving_batching", "test_spans",
 }
 
 

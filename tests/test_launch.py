@@ -139,3 +139,43 @@ def test_batcher_buckets_and_padding():
         assert batch["mask"][len(reqs):].sum() == 0   # padded rows all-masked
     assert seen == set(range(10))
     assert len(b) == 0
+
+
+# ---------------------------------------------------------------------------
+# serve launcher: --spans
+# ---------------------------------------------------------------------------
+
+def test_serve_spans_report(monkeypatch, tmp_path):
+    """--spans adds each flush step's p50/p99 and the pump's flush fill to
+    the printed and written report, beside every pump counter."""
+    import json
+    import sys
+
+    from repro.core import baselines as B
+    from repro.core import trainer as T
+    from repro.launch import serve
+
+    real_fit = B.fit_cloes
+    monkeypatch.setattr(serve.B, "fit_cloes", lambda tr, **kw: real_fit(
+        tr, lcfg=kw["lcfg"], tcfg=T.TrainConfig(epochs=0)))
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    report = tmp_path / "serve.json"
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--requests", "48", "--qps", "2000", "--pump",
+        "--threads", "2", "--plan", "none", "--spans",
+        "--report", str(report)])
+    serve.main()
+    out = json.loads(report.read_text())
+    spans = out["spans"]
+    assert set(spans["spans"]) == {"serve.cycle", "serve.claim",
+                                   "serve.pack", "serve.dispatch",
+                                   "serve.fetch", "serve.resolve"}
+    cycles = out["pump_stats"]["cycles"]
+    for name, row in spans["spans"].items():
+        assert row["flushes"] == cycles, name
+        assert 0.0 <= row["p50_ms"] <= row["p99_ms"], name
+    assert spans["dropped"] == 0
+    stats = out["pump_stats"]
+    assert spans["flush_fill"] == pytest.approx(
+        100.0 * stats["served"] / stats["rows_padded"])
+    assert 0.0 < spans["flush_fill"] <= 100.0

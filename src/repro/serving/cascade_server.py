@@ -23,13 +23,15 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import cascade as C
 from repro.core import losses as L
 from repro.models import base as MB
 from repro.models import zoo as Z
 from repro.serving.batching import RankRequest, RankResponse, RequestBatcher
-from repro.serving.session import CascadeSession, DegradePolicy, ServingConfig
+from repro.serving.session import (CascadeSession, DegradePolicy,
+                                   ServingConfig, split_results)
 
 
 # ---------------------------------------------------------------------------
@@ -143,8 +145,10 @@ class CascadeServer:
         return self.session._rank
 
     def rank_batch(self, batch: dict) -> dict:
-        """Run the jitted hard-cascade pipeline on a padded batch."""
-        return self.session.rank_batch(batch)
+        """Run the jitted hard-cascade pipeline on a padded batch and fetch
+        it: host views scores, survivors, lat and stage_counts."""
+        return split_results(np.asarray(self.session.rank_batch(batch)),
+                             self.cfg.n_stages)
 
     def warmup(self) -> list[tuple[int, int]]:
         """Pre-compile the pipeline for every batcher shape bucket."""

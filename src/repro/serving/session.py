@@ -270,6 +270,17 @@ def _error_response(req: RankRequest, error: str, attempts: int,
     )
 
 
+def split_results(packed: np.ndarray, n_stages: int) -> dict:
+    """The fetched (B, 2G + 1 + S) result of cascade_rank as zero-copy
+    views: scores (B, G), survivors (B, G), lat (B,), stage_counts
+    (B, S)."""
+    g = (packed.shape[-1] - 1 - n_stages) // 2
+    return {"scores": packed[:, :g],
+            "survivors": packed[:, g:2 * g],
+            "lat": packed[:, 2 * g],
+            "stage_counts": packed[:, 2 * g + 1:]}
+
+
 class CascadeSession:
     def __init__(self, params: C.Params, cfg: C.CascadeConfig,
                  lcfg: L.LossConfig | None = None, *,
@@ -301,10 +312,6 @@ class CascadeSession:
         # rank_batch trace.
         P.resolve_plan(self.scfg.plan)
         self.buckets = tuple(sorted(self.scfg.group_buckets))
-        # Only mask (B, G) and m_q (B,) are donated — the only inputs whose
-        # buffers can alias an output shape; donating x/q would just warn
-        # (donation is unsupported on CPU altogether).
-        self._donates = jax.default_backend() != "cpu"
         if pipeline_from is not None:
             # Simulated co-located replicas share ONE warmed jit cache:
             # same params/plan/neural stage -> bit-identical compute, and
@@ -378,8 +385,15 @@ class CascadeSession:
     def _make_rank(self, with_neural: bool):
         # The name is the program's in a trace (jit_cascade_rank).
         def cascade_rank(params: C.Params, x: jax.Array, q: jax.Array,
-                         mask: jax.Array, m_q: jax.Array) -> dict:
-            """Score -> hard filter -> latency estimate, end to end."""
+                         mask: jax.Array, m_q: jax.Array) -> jax.Array:
+            """Score -> hard filter -> latency estimate, end to end, packed
+            into ONE float32 array so a flush fetches it in one transfer.
+            x (B, G, d_x) -> (B, 2G + 1 + S), row b laid out as
+              [:G]        final scores, -inf where the last stage filtered
+              [G:2G]      the last stage's 0/1 survivors
+              [2G]        the Eq-16 latency estimate (ms)
+              [2G + 1:]   per-stage survivor counts (S = n_stages)
+            split_results() is the one reader of this layout."""
             out = P.run_cascade(params, self.cfg, x, q, mask, m_q,
                                 fused=self.scfg.plan)
             surv = out["survivors"][..., -1]
@@ -403,26 +417,18 @@ class CascadeSession:
                 lat = lat + (self.lcfg.latency_scale * self.scfg.neural_cost
                              * surv.sum(-1) / jnp.maximum(mask.sum(-1), 1)
                              * jnp.minimum(m_q, 6000.0))
-            return {
-                "scores": final_scores,
-                "survivors": surv,
-                "stage_survivors": out["survivors"],
-                "est_latency_ms": lat,
-            }
+            # survivors are 0/1 and G <= 256: the counts are exact in f32
+            return jnp.concatenate(
+                [final_scores, surv, lat[:, None], out["kept_per_stage"]],
+                axis=-1)
 
-        donate = (3, 4) if self._donates else ()
-        return jax.jit(cascade_rank, donate_argnums=donate)
+        return jax.jit(cascade_rank)
 
-    def rank_batch(self, batch: dict, *, skip_neural: bool = False) -> dict:
-        """Run the jitted hard-cascade pipeline on a padded batch."""
-        def dev(v):
-            # jnp.asarray is a no-op for a float32 jax array, and donating
-            # that would invalidate the CALLER'S buffer — copy instead.
-            # numpy inputs (the pack_requests path) already land in fresh,
-            # safely-donatable device buffers.
-            if self._donates and isinstance(v, jax.Array):
-                return jnp.array(v, jnp.float32, copy=True)
-            return jnp.asarray(v, jnp.float32)
+    def rank_batch(self, batch: dict, *, skip_neural: bool = False
+                   ) -> jax.Array:
+        """Dispatch the jitted hard-cascade pipeline on a padded batch and
+        return its packed (B, 2G + 1 + S) result on the device, without
+        waiting for it (split_results reads it once fetched)."""
         rank = self._rank_noneural if skip_neural else self._rank
         # A device-pinned replica keeps its compute (and the host->device
         # copies below) on ITS device of the local mesh; unpinned sessions
@@ -433,7 +439,8 @@ class CascadeSession:
             return rank(self.params,
                         jnp.asarray(batch["x"], jnp.float32),
                         jnp.asarray(batch["q"], jnp.float32),
-                        dev(batch["mask"]), dev(batch["m_q"]))
+                        jnp.asarray(batch["mask"], jnp.float32),
+                        jnp.asarray(batch["m_q"], jnp.float32))
 
     def warmup_manifest(self) -> dict:
         """The compilation surface of this session as a JSON-serializable
@@ -793,20 +800,12 @@ class CascadeSession:
             self.faults.on_attempt([e.req.request_id
                                     for e in chunk.entries])
         with self.spans.span(DISPATCH, chunk.flush_id):
-            res = self.rank_batch(batch, skip_neural=chunk.skip_neural)
+            packed = self.rank_batch(batch, skip_neural=chunk.skip_neural)
         with self.spans.span(FETCH, chunk.flush_id):
-            scores = np.asarray(res["scores"])
-            if self.faults is not None:
-                scores = scores.copy()  # device fetches are read-only;
-                #                         the injector corrupts in place
-            out = {
-                "scores": scores,
-                "survivors": np.asarray(res["survivors"]),
-                "lat": np.asarray(res["est_latency_ms"]),
-                "stage_counts": np.asarray(
-                    res["stage_survivors"].sum(axis=1)),
-            }
+            out = split_results(np.asarray(packed), self.cfg.n_stages)
         if self.faults is not None:
+            # the fetched views are read-only; the injector corrupts in place
+            out["scores"] = out["scores"].copy()
             self.faults.on_results(out, len(chunk.entries))
         return out
 

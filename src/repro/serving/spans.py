@@ -13,8 +13,8 @@ join its flush's spans.
   serve.pack      each pack_chunk that stages rows
   serve.dispatch  rank_batch: the host-to-device copies and the call into
                   the jitted pipeline, up to its asynchronous return
-  serve.fetch     the wait for the device, the device-to-host copies and
-                  the stage-count sum
+  serve.fetch     the wait for the device and the one device-to-host copy
+                  of the packed result
   serve.resolve   resolve_chunk / fail_chunk
 
 Retries and bisection give one flush several dispatch and fetch spans;

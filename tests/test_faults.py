@@ -186,11 +186,10 @@ def test_nan_guard_treats_corrupt_output_as_fault():
     calls = {"n": 0}
     def corrupting(batch, **kw):
         calls["n"] += 1
-        out = dict(real(batch, **kw))
+        out = real(batch, **kw)
         if calls["n"] == 1:
-            s = np.asarray(out["scores"]).copy()
-            s[0, 0] = np.nan
-            out["scores"] = s
+            out = np.asarray(out).copy()
+            out[0, 0] = np.nan              # the packed result's scores[0, 0]
         return out
     ses.rank_batch = corrupting
     fut = ses.submit(_req(0, 4, cfg), now_ms=0.0)
@@ -207,11 +206,9 @@ def test_nan_guard_exhaustion_reports_corrupt_output():
     ses = _session(params, cfg)
     real = ses.rank_batch
     def always_corrupt(batch, **kw):
-        out = dict(real(batch, **kw))
-        s = np.asarray(out["scores"]).copy()
-        s[0, 0] = np.inf                    # +inf is corruption; -inf is a
-        out["scores"] = s                   # legitimate filtered score
-        return out
+        out = np.asarray(real(batch, **kw)).copy()
+        out[0, 0] = np.inf                  # scores[0, 0]: +inf is corruption;
+        return out                          # -inf a legitimate filtered score
     ses.rank_batch = always_corrupt
     ses.submit(_req(0, 4, cfg), now_ms=0.0)
     r = ses.flush(1.0)[0]

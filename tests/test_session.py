@@ -3,6 +3,8 @@ deadline-triggered flush ordering, shed-at-capacity admission, degraded-
 mode hysteresis, submit-order invariance across interleaved flushes, and
 zero recompiles after warmup()."""
 
+import contextlib
+
 import jax
 import numpy as np
 import pytest
@@ -13,6 +15,7 @@ from repro.data import features as F
 from repro.serving.batching import RankRequest, RequestBatcher
 from repro.serving.cascade_server import CascadeServer
 from repro.serving.loadgen import run_open_loop
+from repro.serving.pump import SessionPump
 from repro.serving.session import (CascadeSession, DegradePolicy,
                                    FlushPolicy, QueueFull, ServingConfig,
                                    STATUS_OK, STATUS_SHED)
@@ -428,6 +431,54 @@ def test_zero_recompiles_after_warmup_including_degraded_flushes():
         assert all(f.done() for f in futs)
         assert ses._rank._cache_size() == n_compiled, (
             f"round {round_} recompiled the pipeline")
+
+
+@contextlib.contextmanager
+def _compiles():
+    """Names of every program lowered inside the block, jitted or eager,
+    on any thread: JAX reports each lowering to MLIR as one event."""
+    names = []
+
+    def listener(event, duration, **kw):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            names.append(kw.get("fun_name"))
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        yield names
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+
+
+@pytest.mark.parametrize("driver", ["step", "pump"])
+def test_live_flushes_after_warmup_compile_no_program(driver):
+    """After warmup(), live flushes through step() and through a
+    SessionPump compile nothing at all -- not only no new entry in the
+    pipeline's own cache (_rank._cache_size()), which is blind to eager
+    ops. This fails on a fetch that sums the stage counts on the host's
+    initiative: that sum (jit__reduce_sum) compiled at each shape's first
+    live flush, outside the pipeline's cache. jax.clear_caches() first,
+    so an earlier test's eager ops cannot hide such a compile."""
+    jax.clear_caches()
+    params, cfg = _cascade()
+    ses = _session(params, cfg, buckets=(8, 16), batch_groups=4,
+                   flush=FlushPolicy(max_wait_ms=1.0))
+    ses.warmup()
+    sizes = [2, 8, 13, 16, 5, 3, 9, 4, 6, 11, 1]
+    with _compiles() as compiled:
+        if driver == "step":
+            futs = [ses.submit(_req(i, n, cfg), now_ms=0.0)
+                    for i, n in enumerate(sizes)]
+            while ses.step(0.0):
+                pass
+            ses.flush(10.0)
+        else:
+            with SessionPump(ses) as pump:
+                futs = [pump.submit(_req(i, n, cfg))
+                        for i, n in enumerate(sizes)]
+                for f in futs:
+                    f.result(timeout=30.0)
+    assert [f.result().status for f in futs] == [STATUS_OK] * len(sizes)
+    assert compiled == []
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,7 @@ The keep-count and stage-chain semantics are core.pipeline's
 keep_counts_from_lp / filter_chain — imported, not copied, since this
 function doubles as the production non-TPU path and must never fork
 from the pipeline. filter_chain's stable top-k is the double argsort,
-the very construct the kernel replaces with all-pairs ranks, so the
+the very construct the kernel replaces with a counting select, so the
 parity sweeps still compare two algorithmically independent
 formulations of the selection.
 """

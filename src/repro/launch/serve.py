@@ -16,7 +16,7 @@ Two clocks, one lifecycle:
 --spans records the spans of every flush (serving/spans.py: claim, pack,
 dispatch, fetch, resolve, and the pump's whole cycle) and reports each
 one's p50/p99 per flush, with the pumps' flush fill (served rows over
-padded rows).
+padded rows) and item fill (the requests' items over padded items).
 
 Request generation is timed SEPARATELY from the serve phase — the old
 closed-loop launcher started its clock before the submit loop, charging
@@ -134,6 +134,16 @@ def flush_fill(pump_stats: list[dict]) -> float | None:
     return 100.0 * sum(p["served"] for p in pump_stats) / rows
 
 
+def item_fill(pump_stats: list[dict]) -> float | None:
+    """Percent of the pumps' padded items (rows x bucket width) that were
+    a request's own: the padding inside each row, beside flush_fill's
+    padded rows (None without a pump)."""
+    items = sum(p["items_padded"] for p in pump_stats)
+    if not items:
+        return None
+    return 100.0 * sum(p["items_real"] for p in pump_stats) / items
+
+
 def save_serving_state(serve_dir: str, ses: CascadeSession) -> None:
     """The graceful-shutdown write: everything a restarted server needs to
     serve its first request with zero recompiles — params, the configs
@@ -209,7 +219,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--spans", action="store_true",
                     help="record each flush's spans and report their "
-                         "p50/p99 and the flush fill")
+                         "p50/p99, the flush fill and the item fill")
     ap.add_argument("--report", default="",
                     help="write the latency/lifecycle report as JSON here")
     ap.add_argument("--serve-dir", default="",
@@ -400,14 +410,18 @@ def main() -> None:
                   else router_stats["replicas"] if args.pump and router_stats
                   else [])
         spans["flush_fill"] = flush_fill(pumped)
+        spans["item_fill"] = item_fill(pumped)
         for name, row in spans["spans"].items():
             print(f"[serve] span {name}: p50 {row['p50_ms']:.3f}ms p99 "
                   f"{row['p99_ms']:.3f}ms over {row['flushes']} flushes")
-        fill = spans["flush_fill"]
+        fill, items = spans["flush_fill"], spans["item_fill"]
         print("[serve] flush fill: "
               + (f"{fill:.1f}% of padded rows served" if fill is not None
                  else "not counted (no pump)")
               + f"; spans dropped {spans['dropped']}")
+        print("[serve] item fill: "
+              + (f"{items:.1f}% of padded items a request's own"
+                 if items is not None else "not counted (no pump)"))
 
     if res.unresolved or unresolved_after_close:
         raise SystemExit(

@@ -71,8 +71,12 @@ class SessionPump:
         # late arrivals into these — guarded by session.lock
         self._open: dict[int, FlushChunk] = {}
         # rows_padded sums the claimed chunks' pow2-padded capacity:
-        # served / rows_padded is how full the flushes ran
+        # served / rows_padded is how full the flushes ran. items_padded
+        # sums their rows x bucket width, items_real the claimed
+        # requests' item counts (each capped at the bucket):
+        # items_real / items_padded is how full the padded items ran
         self.stats = {"cycles": 0, "served": 0, "rows_padded": 0,
+                      "items_real": 0, "items_padded": 0,
                       "slot_joins": 0, "shutdown_shed": 0,
                       "cycle_errors": 0, "restarts": 0}
         self._thread = threading.Thread(target=self._run, name=name,
@@ -223,6 +227,7 @@ class SessionPump:
             with ses.lock:
                 self.stats["cycles"] += 1
                 self.stats["rows_padded"] += chunk.capacity
+                self.stats["items_padded"] += chunk.capacity * chunk.g
                 if (len(chunk.entries) < chunk.capacity
                         and not self._closing):
                     chunk.open = True
@@ -253,6 +258,11 @@ class SessionPump:
                 chunk.open = False
                 if self._open.get(chunk.g) is chunk:
                     del self._open[chunk.g]
+                # the chunk is closed: its entries, late joiners
+                # included, are the rows it carried
+                self.stats["items_real"] += sum(
+                    min(len(e.req.item_feats), chunk.g)
+                    for e in chunk.entries)
             ses.spans.record(CYCLE, chunk.flush_id, start_ns)
 
     # -- supervision -------------------------------------------------------
@@ -276,9 +286,10 @@ class SessionPump:
                     self._thread.start()
 
     def stats_export(self) -> dict:
-        """Pump counters (cycles/served/rows_padded/slot_joins/
-        shutdown_shed/cycle_errors/restarts) plus the wrapped session's
-        full metrics surface (lifecycle, faults, pool allocated/reused).
+        """Pump counters (cycles/served/rows_padded/items_real/
+        items_padded/slot_joins/shutdown_shed/cycle_errors/restarts) plus
+        the wrapped session's full metrics surface (lifecycle, faults,
+        pool allocated/reused).
         The pump counters are copied under the session lock — every
         mutation site holds it, so a live reporter cannot read a
         half-updated cycle."""

@@ -417,7 +417,7 @@ class CascadeSession:
                 lat = lat + (self.lcfg.latency_scale * self.scfg.neural_cost
                              * surv.sum(-1) / jnp.maximum(mask.sum(-1), 1)
                              * jnp.minimum(m_q, 6000.0))
-            # survivors are 0/1 and G <= 256: the counts are exact in f32
+            # survivors are 0/1 and G < 2^24: the counts are exact in f32
             return jnp.concatenate(
                 [final_scores, surv, lat[:, None], out["kept_per_stage"]],
                 axis=-1)

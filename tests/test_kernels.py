@@ -1,5 +1,7 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracle."""
 
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,7 +80,10 @@ def _assert_filter_parity(x, w, zq, mask, m_q, tol):
 @pytest.mark.parametrize("g", [1, 7,
                                pytest.param(48, marks=pytest.mark.slow),
                                pytest.param(130, marks=pytest.mark.slow),
-                               pytest.param(256, marks=pytest.mark.slow)])
+                               pytest.param(256, marks=pytest.mark.slow),
+                               600,
+                               pytest.param(1024, marks=pytest.mark.slow),
+                               pytest.param(4096, marks=pytest.mark.slow)])
 @pytest.mark.parametrize("d,t", [(24, 3), (8, 1), (40, 5)])
 @pytest.mark.parametrize("dtype", [jnp.float32, _BF16])
 def test_cascade_filter_sweep(g, d, t, dtype):
@@ -114,6 +119,96 @@ def test_cascade_filter_mq_exceeds_group():
                                 tol=1e-5)
     assert (np.asarray(got["n_keep"]) == 16).all()
     assert (np.asarray(got["survivors"])[..., -1] == 1).all()
+
+
+def _edge_case(kind, g):
+    """Two groups of g items in which the select meets one edge."""
+    x, w, zq, mask, m_q = _filter_case(2, g, 24, 3, jnp.float32, seed=g + 5)
+    rng = np.random.default_rng(g)
+    if kind == "ties":
+        # five distinct items, each repeated: every score is tied with
+        # about a fifth of the group, so each cut falls inside a tie class
+        protos = rng.normal(size=(5, 24))
+        x = jnp.asarray(protos[rng.integers(0, 5, (2, g))], jnp.float32)
+    elif kind == "keep_one":
+        zq = jnp.full_like(zq, -30.0)           # pass probs ~1e-13
+    elif kind == "keep_all_alive":
+        zq = jnp.full_like(zq, 30.0)            # pass probs round to 1
+    elif kind == "masked_group":
+        mask = mask.at[1].set(0.0)
+    elif kind == "mq_below_g":
+        m_q = jnp.asarray(rng.integers(1, g // 2, 2), jnp.float32)
+    elif kind == "zero_scores":
+        # logits past ~104: log sigmoid is -0.0 in the reference's cumsum
+        # and +0.0 in the kernel's masked-sum prefix; tied at the top
+        zq = jnp.zeros_like(zq)
+        x = x.at[:, ::3].set(jnp.asarray(np.full(24, 60.0), jnp.float32))
+        w = jnp.abs(w)
+    return x, w, zq, mask, m_q
+
+
+WIDE = [600, pytest.param(1024, marks=pytest.mark.slow),
+        pytest.param(4096, marks=pytest.mark.slow)]
+
+
+@pytest.mark.parametrize("g", [200] + WIDE)
+@pytest.mark.parametrize("kind", ["ties", "keep_one", "keep_all_alive",
+                                  "masked_group", "mq_below_g",
+                                  "zero_scores"])
+def test_cascade_filter_select_edges(kind, g):
+    """At each edge of the select, the counting select equals the stable
+    double argsort bit for bit: with 8-bit digits (200 items) and 4-bit
+    ones past the old 512-item cap."""
+    x, w, zq, mask, m_q = _edge_case(kind, g)
+    got = _assert_filter_parity(x, w, zq, mask, m_q, tol=1e-5)
+    surv = np.asarray(got["survivors"])
+    n_keep = np.asarray(got["n_keep"])
+    alive = np.asarray(mask).sum(-1)
+    if kind == "ties":
+        lp = np.asarray(got["lp"])
+        prev = np.asarray(mask)[..., None] * np.concatenate(
+            [np.ones_like(surv[..., :1]), surv[..., :-1]], -1)
+        inside = 0
+        for b, j in np.ndindex(*n_keep.shape):
+            kept = surv[b, :, j] > 0
+            tau = lp[b, kept, j].min()
+            at_tau = (prev[b, :, j] > 0) & (lp[b, :, j] == tau)
+            inside += 0 < (kept & at_tau).sum() < at_tau.sum()
+        assert inside > 0                       # a cut split a tie class
+    elif kind == "keep_one":
+        assert (n_keep == 1).all()
+        assert (surv.sum(1) == 1).all()
+    elif kind == "keep_all_alive":
+        assert (n_keep == alive[:, None]).all()
+        assert (surv.sum(1) == alive[:, None]).all()
+    elif kind == "masked_group":
+        assert surv[1].sum() == 0
+    elif kind == "zero_scores":
+        assert (np.asarray(got["lp"])[:, ::3] == 0.0).all()
+
+
+@pytest.mark.parametrize("g", WIDE)
+def test_select_orders_signed_zeros_as_equal(g):
+    """A -0.0 / +0.0 pair at the cut is one tie class, as in the stable
+    argsort: the lower index survives whichever sign it carries."""
+    from repro.core.pipeline import filter_chain
+    from repro.kernels.cascade_filter.kernel import _select
+    rng = np.random.default_rng(g)
+    s = -np.abs(rng.normal(size=(1, g))).astype(np.float32) - 1.0
+    zeros = rng.choice(g, 6, replace=False)
+    s[0, zeros] = np.where(np.arange(6) % 2, 0.0, -0.0)
+    alive = rng.random((1, g)) < 0.9
+    alive[0, zeros] = True
+    idx = jnp.arange(g, dtype=jnp.int32)[None, :]
+    for keep, bits in itertools.product((1, 3, 6, 7), (4, 8)):
+        got = _select(jnp.asarray(s), jnp.asarray(alive),
+                      jnp.full((1, 1), float(keep)), idx, bits)
+        want = filter_chain(jnp.asarray(s)[..., None],
+                            jnp.asarray(alive, jnp.float32),
+                            jnp.full((1, 1), float(keep)))[..., 0]
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want))
+        assert np.asarray(got)[0, np.sort(zeros)[:min(keep, 6)]].all()
 
 
 def test_cascade_filter_chain_is_nested():

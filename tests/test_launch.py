@@ -145,9 +145,10 @@ def test_batcher_buckets_and_padding():
 # serve launcher: --spans
 # ---------------------------------------------------------------------------
 
-def test_serve_spans_report(monkeypatch, tmp_path):
-    """--spans adds each flush step's p50/p99 and the pump's flush fill to
-    the printed and written report, beside every pump counter."""
+def test_serve_spans_report(monkeypatch, tmp_path, capsys):
+    """--spans adds each flush step's p50/p99 and the pump's flush and
+    item fill to the printed and written report, beside every pump
+    counter."""
     import json
     import sys
 
@@ -179,3 +180,7 @@ def test_serve_spans_report(monkeypatch, tmp_path):
     assert spans["flush_fill"] == pytest.approx(
         100.0 * stats["served"] / stats["rows_padded"])
     assert 0.0 < spans["flush_fill"] <= 100.0
+    assert spans["item_fill"] == pytest.approx(
+        100.0 * stats["items_real"] / stats["items_padded"])
+    assert 0.0 < spans["item_fill"] <= 100.0
+    assert "[serve] item fill: " in capsys.readouterr().out

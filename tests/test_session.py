@@ -580,3 +580,74 @@ def test_open_loop_light_load_sheds_nothing():
     assert res.unresolved == 0 and res.shed == 0
     assert res.completed == len(reqs)
     assert (res.latency_ms >= 0).all()
+
+
+# ---------------------------------------------------------------------------
+# The wide ladder (bench/configs/cloes3_wide.json): requests of thousands
+# of items through the session, on the filter kernel's body.
+# ---------------------------------------------------------------------------
+
+WIDE_BUCKETS = (16, 64, 256, 1024, 4096)
+
+
+def _unfused(params, cfg, req, g):
+    """run_cascade(fused="none") on the request alone, padded to g."""
+    import jax.numpy as jnp
+    from repro.core import pipeline as P
+    n = min(len(req.item_feats), g)
+    x = np.zeros((1, g, cfg.d_x), np.float32)
+    x[0, :n] = req.item_feats[:n]
+    mask = (np.arange(g) < n).astype(np.float32)[None]
+    out = P.run_cascade(params, cfg, jnp.asarray(x),
+                        jnp.asarray(req.q_feat[None]), jnp.asarray(mask),
+                        jnp.asarray([req.m_q], jnp.float32), fused="none")
+    surv = np.asarray(out["survivors"])[0, :n, -1] > 0
+    scores = np.where(surv, np.asarray(out["scores"])[0, :n], -np.inf)
+    return scores.astype(np.float32), surv
+
+
+@pytest.fixture
+def kernel_body(monkeypatch):
+    """The filter plan runs the Pallas kernel's own body (interpreted)
+    instead of the XLA reference it dispatches to off the chip."""
+    from repro.core import pipeline as P
+    real = P.K.cascade_filter
+    monkeypatch.setattr(P.K, "cascade_filter",
+                        lambda *a, interpret=None: real(*a, interpret=True))
+
+
+def test_wide_ladder_serves_thousands_of_items_untruncated(kernel_body):
+    params, cfg = _cascade()
+    ses = _session(params, cfg, buckets=WIDE_BUCKETS, batch_groups=2)
+    reqs = [_req(0, 2049, cfg), _req(1, 4096, cfg)]
+    futs = [ses.submit(r, now_ms=0.0) for r in reqs]
+    assert [f.bucket for f in futs] == [4096, 4096]
+    ses.flush(0.0)
+    for fut, req in zip(futs, reqs):
+        got = fut.result()
+        assert got.status == STATUS_OK and not got.truncated
+        assert got.degraded == () and len(got.scores) == len(req.item_feats)
+        scores, surv = _unfused(params, cfg, req, 4096)
+        np.testing.assert_array_equal(got.survivors, surv)
+        np.testing.assert_allclose(got.scores, scores, rtol=1e-5, atol=1e-5)
+        assert got.stage_counts[-1] == surv.sum() > 0
+        assert got.survivors.sum() < len(req.item_feats)
+
+
+def test_wide_ladder_degraded_flush_shrinks_4096_to_1024(kernel_body):
+    params, cfg = _cascade()
+    ses = _session(params, cfg, buckets=WIDE_BUCKETS, batch_groups=4,
+                   degrade=DegradePolicy(high_watermark=2, low_watermark=0,
+                                         mq_scale=1.0, shrink_bucket=True))
+    ses.submit(_req(0, 4, cfg), now_ms=0.0)
+    ses.submit(_req(1, 4, cfg), now_ms=0.0)
+    req = _req(2, 4096, cfg)
+    fut = ses.submit(req, now_ms=0.0)       # admitted at the high mark
+    assert ses.degraded and fut.bucket == 1024
+    ses.flush(1.0)
+    got = fut.result()
+    assert "shrink_bucket" in got.degraded and not got.truncated
+    assert len(got.scores) == 1024
+    scores, surv = _unfused(params, cfg, req, 1024)
+    np.testing.assert_array_equal(got.survivors, surv)
+    np.testing.assert_allclose(got.scores, scores, rtol=1e-5, atol=1e-5)

@@ -1,8 +1,8 @@
 """Spans of the serving flush (serving/spans.py): the disabled recorder's
 one shared no-op, the claim/pack/dispatch/fetch/resolve spans nested in
 each pump cycle under one flush id, the response stamps that join them,
-bisection under one id, the pump's rows_padded counter, the profiler
-annotations, and the name of the serving pipeline in a trace."""
+bisection under one id, the pump's rows_padded and item counters, the
+profiler annotations, and the name of the serving pipeline in a trace."""
 
 import glob
 import threading
@@ -161,6 +161,26 @@ def test_rows_padded_sums_the_flushes_padded_rows():
     out = pump.stats_export()
     assert out["rows_padded"] == sum(claimed)
     assert out["served"] == len(resps) <= out["rows_padded"]
+
+
+def test_item_counters_sum_the_flushes_items():
+    """items_padded counts each flush's rows times its bucket's width,
+    items_real the requests' own items, capped at the bucket (an 11-item
+    request fills its 8-item row and is truncated)."""
+    ses, cfg = _session()
+    sizes = [3, 8, 11, 5, 1, 7, 8, 2, 11, 6] * 3
+    with SessionPump(ses) as pump:
+        futs = []
+        for i, n in enumerate(sizes):
+            futs.append(pump.submit(_req(i, cfg, n_items=n)))
+            if i % 4 == 3:
+                time.sleep(0.003)
+        resps = [f.result(timeout=30.0) for f in futs]
+    assert all(r.status == STATUS_OK for r in resps)
+    out = pump.stats_export()
+    assert out["items_padded"] == 8 * out["rows_padded"] > 0
+    assert out["items_real"] == sum(min(n, 8) for n in sizes)
+    assert out["items_real"] < out["items_padded"]
 
 
 def test_bisection_under_a_poison_keeps_one_flush_id():

@@ -6,7 +6,7 @@ primitives it implements, VMEM limits); the TPU compiler installed with
 jaxlib does, and it needs only a topology description and shapes. These
 tests compile with interpret=False at the shapes the system runs: CLOES
 d_x = 24 and T = 3 (configs/cloes.py), serving batches of up to 32
-groups in the 16/64/256 buckets (and the filter's 512-item cap), and
+groups in the 16/64/256/1024/4096 buckets (4096 is the filter's cap), and
 training minibatches of 64 groups of 64 items. Nothing runs, so they say
 nothing about results or time. Each kernel's custom call carries the
 `name=` its pallas_call sets: the profiler shows that name, and the
@@ -78,7 +78,7 @@ def _compile(fn, sharding, *shapes, names=()):
     return text
 
 
-@pytest.mark.parametrize("g", [16, 64, 256, 512])
+@pytest.mark.parametrize("g", [16, 64, 256, 512, 1024, 4096])
 def test_cascade_filter_compiles(one_chip, g):
     b = 32
     _compile(lambda *a: cascade_filter(*a, interpret=False), one_chip,
@@ -120,18 +120,28 @@ def test_cascade_loss_gradient_keeps_the_kernel_names(one_chip):
              names=["cascade_loss", "cascade_loss_bwd"])
 
 
-def test_run_cascade_filter_plan_compiles(one_chip):
-    """The whole serving pipeline at the largest bucket and batch."""
-    b, g = 32, 256
+def _compile_run_cascade(sharding, b, g):
     masks = F.default_stage_masks(T)
     cfg = C.CascadeConfig(T, F.N_FEATURES, F.N_QUERY_BUCKETS, masks,
                           F.stage_costs(masks))
     params = jax.eval_shape(lambda: C.init_params(cfg, jax.random.PRNGKey(0)))
     params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=one_chip), params)
-    x, q, mask, m_q = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+        a.shape, a.dtype, sharding=sharding), params)
+    x, q, mask, m_q = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
                        for s in ((b, g, cfg.d_x), (b, cfg.d_q), (b, g), (b,))]
     text = jax.jit(lambda p, *batch: P.run_cascade(
         p, cfg, *batch, fused="filter", interpret=False)).lower(
             params, x, q, mask, m_q).compile().as_text()
     assert kernel_names(text) == {"cascade_filter"}
+
+
+def test_run_cascade_filter_plan_compiles(one_chip):
+    """The whole serving pipeline at the normal ladder's largest bucket
+    and batch."""
+    _compile_run_cascade(one_chip, 32, 256)
+
+
+def test_run_cascade_filter_plan_compiles_at_4096(one_chip):
+    """The whole serving pipeline at the wide ladder's largest bucket
+    (cloes3_wide) and batch."""
+    _compile_run_cascade(one_chip, 32, 4096)

@@ -44,6 +44,7 @@ from repro.core import losses as L
 from repro.core import pipeline as P
 from repro.data import features as F
 from repro.kernels import ops as K
+from repro.serving.batching import alloc_batch
 from repro.serving.cascade_server import CascadeServer
 
 BUCKETS = [(32, 64), (32, 256)]
@@ -52,12 +53,12 @@ BENCH_JSON = "BENCH_serving.json"
 
 def _batch(b, g, d_x, d_q, seed=0):
     rng = np.random.default_rng(seed)
-    return {
-        "x": rng.normal(size=(b, g, d_x)).astype(np.float32),
-        "q": np.eye(d_q)[rng.integers(0, d_q, b)].astype(np.float32),
-        "mask": np.ones((b, g), np.float32),
-        "m_q": rng.integers(g, 20 * g, b).astype(np.float32),
-    }
+    batch = alloc_batch(b, g, d_x, d_q)
+    batch["x"][...] = rng.normal(size=(b, g, d_x))
+    batch["q"][...] = np.eye(d_q)[rng.integers(0, d_q, b)]
+    batch["mask"][...] = 1.0
+    batch["m_q"][...] = rng.integers(g, 20 * g, b)
+    return batch
 
 
 def _seed_rank_batch(params, cfg, lcfg, batch):

@@ -256,7 +256,7 @@ def phase_serve(params, cfg, lcfg, reqs, plan: str, size: Size, seed: int):
     contract. Returns {request_id: RankResponse}."""
     import jax
     from repro.launch.serve import build_session, compiled_count
-    from repro.serving.batching import bucket_of
+    from repro.serving.batching import bucket_of, staging_width
     from repro.serving.loadgen import run_open_loop
     ses = build_session(params, cfg, lcfg, plan=plan, max_queue=0)
     t0 = time.perf_counter()
@@ -293,11 +293,9 @@ def phase_serve(params, cfg, lcfg, reqs, plan: str, size: Size, seed: int):
         fail(f"serve {plan}: a bucket served no request: {per_bucket}")
     b, g = ses.scfg.batch_groups, max(ses.buckets)
 
-    def spec(*shape):
-        return jax.ShapeDtypeStruct(shape, np.float32)
-
-    text = ses._rank.lower(ses.params, spec(b, g, cfg.d_x), spec(b, cfg.d_q),
-                           spec(b, g), spec(b)).compile().as_text()
+    buf = jax.ShapeDtypeStruct((b, staging_width(g, cfg.d_x, cfg.d_q)),
+                               np.float32)
+    text = ses._rank.lower(ses.params, buf).compile().as_text()
     check_kernel(text, f"serve plan={plan} pipeline ({b}, {g})")
     return resps
 

@@ -10,12 +10,14 @@ Jit construction is allowed at module scope (decorators, module-level
 wrappers) and inside the blessed pipeline/warmup modules that build the
 compiled ladder exactly once.
 
-CL004 (adhoc-batch-shape): the staging-batch layout is the exact dict
-``{"x", "q", "mask", "m_q"}`` and every live instance must come from
+CL004 (adhoc-batch-shape): a staging batch is one (B, W) buffer under
+``"buf"`` (the flush's one host-to-device copy) with its ``x``, ``q``,
+``mask`` and ``m_q`` views, and every live instance must come from
 ``alloc_batch`` / the warmed pow2 ladder.  A hand-rolled literal with
-exactly that key set (or an ``alloc_batch`` call) outside the bucket/
-warmup code is a new (B, G) shape the warmup never compiled.  The
-trainer's engine batches are supersets of this key set and do not match.
+those view keys, with or without ``"buf"``, (or an ``alloc_batch`` call)
+outside the bucket/warmup code is a new (B, G) shape the warmup never
+compiled, or a second staging path beside the one buffer.  The trainer's
+engine batches carry further keys and do not match.
 
 Scope: ``src/repro`` only.
 """
@@ -45,10 +47,11 @@ BLESSED_FUNCTIONS = {
     ("src/repro/serving/session.py", "_make_rank"),
 }
 
-# The staging layout (serving/batching.py alloc_batch).  Exact match only:
-# trainer engine batches carry x/q/mask/m_q PLUS y/wgt/... and are a
-# different contract.
-STAGING_KEYS = frozenset({"x", "q", "mask", "m_q"})
+# The staging layout (serving/batching.py alloc_batch): the views, and the
+# buffer they view.  Exact match only: trainer engine batches carry
+# x/q/mask/m_q PLUS y/wgt/... and are a different contract.
+STAGING_VIEWS = frozenset({"x", "q", "mask", "m_q"})
+STAGING_BUFFER = "buf"
 
 # Where the layout may legitimately be built.
 BLESSED_SHAPE_FILES = ("src/repro/serving/batching.py",)
@@ -104,8 +107,8 @@ def check(files: list[ParsedFile]) -> list[Finding]:
                     keys = {k.value for k in node.keys
                             if isinstance(k, ast.Constant)
                             and isinstance(k.value, str)}
-                    if len(node.keys) == len(STAGING_KEYS) \
-                            and keys == STAGING_KEYS:
+                    if len(keys) == len(node.keys) \
+                            and keys - {STAGING_BUFFER} == STAGING_VIEWS:
                         findings.append(Finding(
                             "CL004", pf.rel, node.lineno,
                             f"hand-rolled staging batch in `{qual}` — "

@@ -16,7 +16,8 @@ Two clocks, one lifecycle:
 --spans records the spans of every flush (serving/spans.py: claim, pack,
 dispatch, fetch, resolve, and the pump's whole cycle) and reports each
 one's p50/p99 per flush, with the pumps' flush fill (served rows over
-padded rows) and item fill (the requests' items over padded items).
+padded rows), item fill (the requests' items over padded items) and the
+host-to-device bytes per real item (h2d_bytes over items_real).
 
 Request generation is timed SEPARATELY from the serve phase — the old
 closed-loop launcher started its clock before the submit loop, charging
@@ -142,6 +143,16 @@ def item_fill(pump_stats: list[dict]) -> float | None:
     if not items:
         return None
     return 100.0 * sum(p["items_real"] for p in pump_stats) / items
+
+
+def h2d_bytes_per_item(pump_stats: list[dict]) -> float | None:
+    """Staging bytes copied to the device per real item: what a flush
+    moves for each item it serves, its padding included (None without a
+    pump)."""
+    items = sum(p["items_real"] for p in pump_stats)
+    if not items:
+        return None
+    return sum(p["h2d_bytes"] for p in pump_stats) / items
 
 
 def save_serving_state(serve_dir: str, ses: CascadeSession) -> None:
@@ -411,17 +422,22 @@ def main() -> None:
                   else [])
         spans["flush_fill"] = flush_fill(pumped)
         spans["item_fill"] = item_fill(pumped)
+        spans["h2d_bytes_per_item"] = h2d_bytes_per_item(pumped)
         for name, row in spans["spans"].items():
             print(f"[serve] span {name}: p50 {row['p50_ms']:.3f}ms p99 "
                   f"{row['p99_ms']:.3f}ms over {row['flushes']} flushes")
         fill, items = spans["flush_fill"], spans["item_fill"]
+        h2d = spans["h2d_bytes_per_item"]
         print("[serve] flush fill: "
               + (f"{fill:.1f}% of padded rows served" if fill is not None
                  else "not counted (no pump)")
               + f"; spans dropped {spans['dropped']}")
         print("[serve] item fill: "
               + (f"{items:.1f}% of padded items a request's own"
-                 if items is not None else "not counted (no pump)"))
+                 if items is not None else "not counted (no pump)")
+              + "; host-to-device: "
+              + (f"{h2d:.1f} bytes per real item" if h2d is not None
+                 else "not counted (no pump)"))
 
     if res.unresolved or unresolved_after_close:
         raise SystemExit(

@@ -74,9 +74,12 @@ class SessionPump:
         # served / rows_padded is how full the flushes ran. items_padded
         # sums their rows x bucket width, items_real the claimed
         # requests' item counts (each capped at the bucket):
-        # items_real / items_padded is how full the padded items ran
+        # items_real / items_padded is how full the padded items ran.
+        # h2d_bytes sums the staging buffers their dispatched attempts
+        # copied to the device: h2d_bytes / items_real is what a flush
+        # moves per real item, padding included
         self.stats = {"cycles": 0, "served": 0, "rows_padded": 0,
-                      "items_real": 0, "items_padded": 0,
+                      "items_real": 0, "items_padded": 0, "h2d_bytes": 0,
                       "slot_joins": 0, "shutdown_shed": 0,
                       "cycle_errors": 0, "restarts": 0}
         self._thread = threading.Thread(target=self._run, name=name,
@@ -263,6 +266,7 @@ class SessionPump:
                 self.stats["items_real"] += sum(
                     min(len(e.req.item_feats), chunk.g)
                     for e in chunk.entries)
+                self.stats["h2d_bytes"] += chunk.h2d_bytes
             ses.spans.record(CYCLE, chunk.flush_id, start_ns)
 
     # -- supervision -------------------------------------------------------
@@ -287,9 +291,9 @@ class SessionPump:
 
     def stats_export(self) -> dict:
         """Pump counters (cycles/served/rows_padded/items_real/
-        items_padded/slot_joins/shutdown_shed/cycle_errors/restarts) plus
-        the wrapped session's full metrics surface (lifecycle, faults,
-        pool allocated/reused).
+        items_padded/h2d_bytes/slot_joins/shutdown_shed/cycle_errors/
+        restarts) plus the wrapped session's full metrics surface
+        (lifecycle, faults, pool allocated/reused).
         The pump counters are copied under the session lock — every
         mutation site holds it, so a live reporter cannot read a
         half-updated cycle."""

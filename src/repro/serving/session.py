@@ -37,7 +37,6 @@ CascadeServer.serve().
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import itertools
 import math
@@ -52,9 +51,9 @@ from repro.core import cascade as C
 from repro.core import losses as L
 from repro.core import pipeline as P
 from repro.serving.batching import (RankRequest, RankResponse,
-                                    TransferBufferPool, bucket_of,
-                                    pack_into, padded_batch_rows,
-                                    warmup_batch_sizes)
+                                    TransferBufferPool, alloc_batch,
+                                    bucket_of, pack_into, padded_batch_rows,
+                                    staging_views, warmup_batch_sizes)
 from repro.serving.faults import CorruptOutput, FaultInjector
 from repro.serving.spans import (CLAIM, DISPATCH, FETCH, PACK, RESOLVE,
                                  SpanRecorder)
@@ -240,6 +239,8 @@ class FlushChunk:
     batch: dict | None = None       # pooled staging buffer once packed
     mq_applied: bool = False        # mq_scale already folded into the
     # staged m_q column (must happen exactly once across retry attempts)
+    h2d_bytes: int = 0              # staging bytes its dispatched attempts
+    # (bisection halves' included) copied to the device
 
 
 def _shed_response(req: RankRequest) -> RankResponse:
@@ -384,16 +385,26 @@ class CascadeSession:
 
     def _make_rank(self, with_neural: bool):
         # The name is the program's in a trace (jit_cascade_rank).
-        def cascade_rank(params: C.Params, x: jax.Array, q: jax.Array,
-                         mask: jax.Array, m_q: jax.Array) -> jax.Array:
-            """Score -> hard filter -> latency estimate, end to end, packed
-            into ONE float32 array so a flush fetches it in one transfer.
-            x (B, G, d_x) -> (B, 2G + 1 + S), row b laid out as
+        def cascade_rank(params: C.Params, buf: jax.Array) -> jax.Array:
+            """Score -> hard filter -> latency estimate, end to end, from
+            ONE staged float32 input to ONE packed float32 output, so a
+            flush moves one array each way. buf (B, W) is the staging
+            buffer (batching.alloc_batch), unpacked here by the same
+            staging_views that pack_into writes through; the result is
+            (B, 2G + 1 + S), row b laid out as
               [:G]        final scores, -inf where the last stage filtered
               [G:2G]      the last stage's 0/1 survivors
               [2G]        the Eq-16 latency estimate (ms)
               [2G + 1:]   per-stage survivor counts (S = n_stages)
             split_results() is the one reader of this layout."""
+            v = staging_views(buf, self.cfg.d_x, self.cfg.d_q,
+                              fence=jax.lax.optimization_barrier)
+            # The barrier lands the four unpacked arrays before the
+            # pipeline reads them, so the pipeline compiles as it would
+            # from four inputs: unpacking adds a copy, never a change of
+            # fusion (and so of summation order) downstream.
+            x, q, mask, m_q = jax.lax.optimization_barrier(
+                (v["x"], v["q"], v["mask"], v["m_q"]))
             out = P.run_cascade(params, self.cfg, x, q, mask, m_q,
                                 fused=self.scfg.plan)
             surv = out["survivors"][..., -1]
@@ -426,21 +437,15 @@ class CascadeSession:
 
     def rank_batch(self, batch: dict, *, skip_neural: bool = False
                    ) -> jax.Array:
-        """Dispatch the jitted hard-cascade pipeline on a padded batch and
-        return its packed (B, 2G + 1 + S) result on the device, without
-        waiting for it (split_results reads it once fetched)."""
+        """Dispatch the jitted hard-cascade pipeline on a staging batch
+        (alloc_batch layout) and return its packed (B, 2G + 1 + S) result
+        on the device, without waiting for it (split_results reads it
+        once fetched). The batch's one "buf" array is its ONE
+        host-to-device copy; a device-pinned replica puts it, and so runs
+        the pipeline, on ITS device of the local mesh (device=None: the
+        default device)."""
         rank = self._rank_noneural if skip_neural else self._rank
-        # A device-pinned replica keeps its compute (and the host->device
-        # copies below) on ITS device of the local mesh; unpinned sessions
-        # serve on the default device exactly as before.
-        ctx = (jax.default_device(self.device) if self.device is not None
-               else contextlib.nullcontext())
-        with ctx:
-            return rank(self.params,
-                        jnp.asarray(batch["x"], jnp.float32),
-                        jnp.asarray(batch["q"], jnp.float32),
-                        jnp.asarray(batch["mask"], jnp.float32),
-                        jnp.asarray(batch["m_q"], jnp.float32))
+        return rank(self.params, jax.device_put(batch["buf"], self.device))
 
     def warmup_manifest(self) -> dict:
         """The compilation surface of this session as a JSON-serializable
@@ -490,12 +495,9 @@ class CascadeSession:
                          and self._rank_noneural is not self._rank)
         shapes = []
         for b, g in manifest["shapes"]:
-            batch = {
-                "x": np.zeros((b, g, self.cfg.d_x), np.float32),
-                "q": np.zeros((b, self.cfg.d_q), np.float32),
-                "mask": np.ones((b, g), np.float32),
-                "m_q": np.full((b,), float(g), np.float32),
-            }
+            batch = alloc_batch(b, g, self.cfg.d_x, self.cfg.d_q)
+            batch["mask"][...] = 1.0
+            batch["m_q"][...] = float(g)
             self.rank_batch(batch)
             if warm_degraded:
                 self.rank_batch(batch, skip_neural=True)
@@ -801,6 +803,7 @@ class CascadeSession:
                                     for e in chunk.entries])
         with self.spans.span(DISPATCH, chunk.flush_id):
             packed = self.rank_batch(batch, skip_neural=chunk.skip_neural)
+        chunk.h2d_bytes += batch["buf"].nbytes
         with self.spans.span(FETCH, chunk.flush_id):
             out = split_results(np.asarray(packed), self.cfg.n_stages)
         if self.faults is not None:
@@ -890,10 +893,10 @@ class CascadeSession:
         # take its chunk-mates down with it. Halves pack into the warmed
         # pow2 shape ladder — no recompiles under quarantine.
         mid = n // 2
-        out_l = self._execute_with_retry(
-            self._subchunk(chunk, chunk.entries[:mid]))
-        out_r = self._execute_with_retry(
-            self._subchunk(chunk, chunk.entries[mid:]))
+        halves = (self._subchunk(chunk, chunk.entries[:mid]),
+                  self._subchunk(chunk, chunk.entries[mid:]))
+        out_l, out_r = [self._execute_with_retry(h) for h in halves]
+        chunk.h2d_bytes += sum(h.h2d_bytes for h in halves)
         merged = {k: np.concatenate([out_l[k], out_r[k]])
                   for k in ("scores", "survivors", "lat", "stage_counts")}
         merged["error"] = out_l["error"] + out_r["error"]

@@ -11,8 +11,9 @@ join its flush's spans.
                   service_ms to the end of resolve (never annotated)
   serve.claim     claim_due / claim_bucket, lock wait included
   serve.pack      each pack_chunk that stages rows
-  serve.dispatch  rank_batch: the host-to-device copies and the call into
-                  the jitted pipeline, up to its asynchronous return
+  serve.dispatch  rank_batch: the one host-to-device copy of the staging
+                  buffer and the call into the jitted pipeline, up to its
+                  asynchronous return
   serve.fetch     the wait for the device and the one device-to-host copy
                   of the packed result
   serve.resolve   resolve_chunk / fail_chunk

@@ -75,6 +75,16 @@ def test_fixture_flags_seeded_violation(fixture, rule, live_findings):
     assert rule not in {f.rule for f in live_findings}
 
 
+def test_cl004_fires_on_both_hand_rolled_staging_forms():
+    """The one-buffer layout built by hand, and the four-array form that
+    would be a second path to the device, each outside the bucket/warmup
+    code."""
+    files = core.collect_files([FIX / "bad_shape.py"])
+    hits = [f for f in core.run(files) if f.rule == "CL004"]
+    assert sorted(f.why.split("`")[1] for f in hits) == [
+        "four_array_batch", "handmade_batch"]
+
+
 def test_default_walk_skips_fixture_corpus():
     files = core.collect_files(core.default_targets())
     assert not any("analysis/fixtures" in f.rel for f in files)

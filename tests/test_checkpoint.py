@@ -23,6 +23,7 @@ from repro.core import losses as L
 from repro.core import trainer as T
 from repro.data import features as F
 from repro.data import LogConfig, generate_log
+from repro.serving.batching import alloc_batch
 from repro.serving.faults import FsFaultConfig, FsFaultInjector
 from repro.serving.session import CascadeSession, ServingConfig
 
@@ -362,12 +363,12 @@ def test_warm_restart_replays_manifest_with_zero_new_compiles(tmp_path):
     compiled = ses2._rank._cache_size()
     # live traffic on every warmed shape: zero new compiles
     for b, g in shapes:
-        ses2.rank_batch({
-            "x": np.random.default_rng(0).normal(
-                size=(b, g, cfg.d_x)).astype(np.float32),
-            "q": np.zeros((b, cfg.d_q), np.float32),
-            "mask": np.ones((b, g), np.float32),
-            "m_q": np.full((b,), float(g), np.float32)})
+        batch = alloc_batch(b, g, cfg.d_x, cfg.d_q)
+        batch["x"][...] = np.random.default_rng(0).normal(
+            size=(b, g, cfg.d_x))
+        batch["mask"][...] = 1.0
+        batch["m_q"][...] = float(g)
+        ses2.rank_batch(batch)
     assert ses2._rank._cache_size() == compiled
 
 

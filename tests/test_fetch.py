@@ -1,9 +1,11 @@
-"""The serving program's one fetch: `cascade_rank` returns one packed
-float32 array, `rank_batch` hands it back as a single device array, and
-`split_results` reads it into host views that must equal, bit for bit,
-what `core.pipeline.run_cascade` and the Eq-16 latency give directly --
-scores with their -infs, last-stage survivors, latency, and per-stage
-counts equal to the per-stage survivor masks summed over items."""
+"""The serving program's one transfer each way: `rank_batch` copies a
+batch's one staging buffer (`alloc_batch`) to the device, `cascade_rank`
+unpacks it and returns one packed float32 array, and `split_results`
+reads that into host views that must equal, bit for bit, what
+`core.pipeline.run_cascade` and the Eq-16 latency give directly on the
+same x, q, mask and m_q -- scores with their -infs, last-stage
+survivors, latency, and per-stage counts equal to the per-stage survivor
+masks summed over items."""
 
 import dataclasses
 
@@ -17,10 +19,12 @@ from repro.core import cascade as C
 from repro.core import losses as L
 from repro.core import pipeline as P
 from repro.data import features as F
+from repro.serving.batching import alloc_batch, staging_width
 from repro.serving.cascade_server import NeuralScorer
 from repro.serving.session import CascadeSession, ServingConfig, split_results
 
 BATCH_GROUPS = 4
+WIDE_LADDER = (16, 64, 256, 1024, 4096)
 
 
 def _cascade():
@@ -38,18 +42,20 @@ def _neural():
 
 
 def _batch(b, g, cfg, seed):
-    """A padded batch as pack_into stages it: ragged valid items (masked
-    ones zero), one-hot query buckets, m_q above the valid count."""
+    """A staging batch (alloc_batch) filled as pack_into stages it:
+    ragged valid items (masked ones zero), one-hot query buckets, m_q
+    above the valid count."""
     rng = np.random.default_rng(seed)
     n_valid = rng.integers(1, g + 1, b)
     n_valid[0] = g
     mask = (np.arange(g)[None, :] < n_valid[:, None]).astype(np.float32)
-    x = rng.normal(size=(b, g, cfg.d_x)).astype(np.float32) * mask[..., None]
-    return {"x": x,
-            "q": np.eye(cfg.d_q)[rng.integers(0, cfg.d_q, b)]
-            .astype(np.float32),
-            "mask": mask,
-            "m_q": (n_valid * rng.integers(1, 40, b)).astype(np.float32)}
+    batch = alloc_batch(b, g, cfg.d_x, cfg.d_q)
+    batch["x"][...] = (rng.normal(size=(b, g, cfg.d_x)).astype(np.float32)
+                       * mask[..., None])
+    batch["q"][...] = np.eye(cfg.d_q)[rng.integers(0, cfg.d_q, b)]
+    batch["mask"][...] = mask
+    batch["m_q"][...] = n_valid * rng.integers(1, 40, b)
+    return batch
 
 
 def _direct(ses, batch, with_neural):
@@ -81,6 +87,7 @@ def _direct(ses, batch, with_neural):
 
 
 @pytest.mark.parametrize("plan,g,rows,neural", [
+    ("filter", 4096, 2, None),                   # the wide ladder's top
     ("filter", 16, BATCH_GROUPS, None),
     ("filter", 64, BATCH_GROUPS, None),
     ("filter", 256, BATCH_GROUPS, None),
@@ -96,7 +103,8 @@ def test_split_result_is_bit_identical_to_the_pipeline(plan, g, rows, neural):
     ses = CascadeSession(
         params, cfg, L.LossConfig(),
         neural_stage=_neural() if neural else None,
-        scfg=ServingConfig(plan=plan, group_buckets=(16, 64, 256),
+        scfg=ServingConfig(plan=plan, group_buckets=WIDE_LADDER
+                           if g > 256 else (16, 64, 256),
                            batch_groups=BATCH_GROUPS))
     batch = _batch(rows, g, cfg, seed=g + rows)
     packed = ses.rank_batch(batch, skip_neural=neural == "skip_neural")
@@ -119,3 +127,35 @@ def test_split_result_is_bit_identical_to_the_pipeline(plan, g, rows, neural):
     # the filter really filtered: masked items and cut survivors are -inf
     assert np.isneginf(got["scores"][batch["mask"] == 0]).all()
     assert np.isneginf(got["scores"]).sum() > (batch["mask"] == 0).sum()
+
+
+@pytest.mark.parametrize("neural", [None, "neural"])
+def test_cascade_rank_takes_the_params_and_one_array(neural, monkeypatch):
+    """The lowered jit_cascade_rank takes the params' leaves plus exactly
+    ONE array, the (B, W) staging buffer, and rank_batch copies that one
+    buffer to the device once per call."""
+    params, cfg = _cascade()
+    ses = CascadeSession(
+        params, cfg, L.LossConfig(),
+        neural_stage=_neural() if neural else None,
+        scfg=ServingConfig(plan="filter", group_buckets=(16,),
+                           batch_groups=BATCH_GROUPS))
+    batch = _batch(BATCH_GROUPS, 16, cfg, seed=7)
+    width = staging_width(16, cfg.d_x, cfg.d_q)
+    assert batch["buf"].shape == (BATCH_GROUPS, width)
+    lowered = ses._rank.lower(ses.params, batch["buf"])
+    n_params = len(jax.tree_util.tree_leaves(ses.params))
+    args = jax.tree_util.tree_leaves(lowered.args_info)
+    assert len(args) == n_params + 1
+    assert args[-1].shape == (BATCH_GROUPS, width)
+    assert "jit_cascade_rank" in lowered.as_text()
+
+    real_put, puts = jax.device_put, []
+
+    def counting_put(x, *a, **k):
+        puts.append(x)
+        return real_put(x, *a, **k)
+
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    np.asarray(ses.rank_batch(batch))
+    assert len(puts) == 1 and puts[0] is batch["buf"]

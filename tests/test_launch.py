@@ -183,4 +183,10 @@ def test_serve_spans_report(monkeypatch, tmp_path, capsys):
     assert spans["item_fill"] == pytest.approx(
         100.0 * stats["items_real"] / stats["items_padded"])
     assert 0.0 < spans["item_fill"] <= 100.0
-    assert "[serve] item fill: " in capsys.readouterr().out
+    assert spans["h2d_bytes_per_item"] == pytest.approx(
+        stats["h2d_bytes"] / stats["items_real"])
+    assert spans["h2d_bytes_per_item"] > 0.0
+    printed = capsys.readouterr().out
+    assert "[serve] item fill: " in printed
+    assert (f"host-to-device: {spans['h2d_bytes_per_item']:.1f} bytes per "
+            "real item") in printed

@@ -9,7 +9,9 @@ import pytest
 from repro.core import cascade as C
 from repro.core import losses as L
 from repro.data import features as F
-from repro.serving.batching import RankRequest, RequestBatcher
+from repro.serving.batching import (RankRequest, RequestBatcher,
+                                    TransferBufferPool, alloc_batch,
+                                    pack_into, staging_width)
 from repro.serving.cascade_server import CascadeServer
 
 
@@ -79,6 +81,55 @@ def test_drain_seqs_track_submit_positions():
         # seqs are exactly each request's position in the submit stream
         assert [order[s] for s in seqs] == [len(r.item_feats) for r in reqs]
         assert seqs == sorted(seqs)           # stable within a bucket
+
+
+# ---------------------------------------------------------------------------
+# The staging layout: one (b, W) buffer per batch, the four arrays views
+# into it, rows laid out [x (g * d_x) | mask (g) | q (d_q) | m_q (1)].
+# ---------------------------------------------------------------------------
+
+LAYOUT_D_X, LAYOUT_D_Q = 24, 8
+
+
+@pytest.mark.parametrize("g", [16, 256, 4096])
+def test_staging_batch_is_one_buffer_in_the_documented_row_layout(g):
+    d_x, d_q, b = LAYOUT_D_X, LAYOUT_D_Q, 2
+    batch = alloc_batch(b, g, d_x, d_q)
+    buf = batch["buf"]
+    w = staging_width(g, d_x, d_q)
+    assert w == g * (d_x + 1) + d_q + 1
+    assert buf.shape == (b, w) and buf.dtype == np.float32
+    assert buf.flags["C_CONTIGUOUS"] and not buf.any()
+    views = {"x": (b, g, d_x), "mask": (b, g), "q": (b, d_q), "m_q": (b,)}
+    assert set(batch) == {"buf", *views}
+    for k, shape in views.items():
+        assert batch[k].shape == shape and batch[k].dtype == np.float32
+        assert np.shares_memory(batch[k], buf), k
+    # pack_into's writes land in the one buffer, row by row
+    reqs = [_req(0, g - 3, d_x=d_x, d_q=d_q), _req(1, g + 5, d_x=d_x,
+                                                 d_q=d_q)]
+    pack_into(batch, reqs, g)
+    for i, r in enumerate(reqs):
+        n = min(len(r.item_feats), g)
+        row = buf[i]
+        x = np.zeros((g, d_x), np.float32)
+        x[:n] = r.item_feats[:n]
+        np.testing.assert_array_equal(row[:g * d_x], x.ravel())
+        np.testing.assert_array_equal(row[g * d_x:g * (d_x + 1)],
+                                      np.arange(g) < n)
+        np.testing.assert_array_equal(row[g * (d_x + 1):-1], r.q_feat)
+        assert row[-1] == r.m_q
+    # the pool hands the same buffer back fully zeroed, keyed by (b, g)
+    pool = TransferBufferPool(d_x, d_q)
+    got = pool.acquire(b, g)
+    got["buf"][...] = 5.0
+    pool.release(got)
+    assert pool.acquire(b, 2 * g) is not got     # another (b, g): fresh
+    again = pool.acquire(b, g)
+    assert again is got and not again["buf"].any()
+    for k in views:
+        assert np.shares_memory(again[k], again["buf"])
+    assert pool.allocated == 2 and pool.reused == 1
 
 
 # ---------------------------------------------------------------------------

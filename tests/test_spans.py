@@ -1,8 +1,9 @@
 """Spans of the serving flush (serving/spans.py): the disabled recorder's
 one shared no-op, the claim/pack/dispatch/fetch/resolve spans nested in
 each pump cycle under one flush id, the response stamps that join them,
-bisection under one id, the pump's rows_padded and item counters, the
-profiler annotations, and the name of the serving pipeline in a trace."""
+bisection under one id, the pump's rows_padded, item and h2d_bytes
+counters, the profiler annotations, and the name of the serving pipeline
+in a trace."""
 
 import glob
 import threading
@@ -16,7 +17,8 @@ from repro.core import cascade as C
 from repro.core import losses as L
 from repro.data import features as F
 from repro.serving import spans as S
-from repro.serving.batching import RankRequest, padded_batch_rows
+from repro.serving.batching import (RankRequest, alloc_batch,
+                                    padded_batch_rows)
 from repro.serving.faults import FaultConfig, FaultInjector
 from repro.serving.pump import SessionPump
 from repro.serving.session import (STATUS_ERROR, STATUS_OK, CascadeSession,
@@ -183,6 +185,30 @@ def test_item_counters_sum_the_flushes_items():
     assert out["items_real"] < out["items_padded"]
 
 
+@pytest.mark.parametrize("poison", [(), (5,)])
+def test_h2d_bytes_sums_the_staged_buffers(poison):
+    """h2d_bytes sums the nbytes of every staging buffer a dispatched
+    attempt copied to the device, bisection halves included, under a
+    live pump."""
+    ses, cfg = _session(faults=FaultInjector(FaultConfig(
+        poison_ids=poison)))
+    ses._sleep = lambda s: None
+    staged = []
+    real = ses.rank_batch
+
+    def recording(batch, **kw):
+        out = real(batch, **kw)
+        staged.append(batch["buf"].nbytes)
+        return out
+
+    ses.rank_batch = recording
+    pump, resps, _ = _serve_through_pump(ses, cfg)
+    assert [r.status for r in resps].count(STATUS_ERROR) == len(poison)
+    out = pump.stats_export()
+    assert out["h2d_bytes"] == pump.stats["h2d_bytes"] == sum(staged) > 0
+    assert out["h2d_bytes"] >= 4 * cfg.d_x * out["items_real"]
+
+
 def test_bisection_under_a_poison_keeps_one_flush_id():
     rec = S.SpanRecorder(enabled=True)
     ses, cfg = _session(spans=rec,
@@ -231,11 +257,8 @@ def test_annotated_steps_land_on_the_host_plane_and_the_cycle_does_not(
 
 def test_serving_pipeline_is_named_cascade_rank():
     ses, cfg = _session()
-    b, g = BATCH, 8
-    text = ses._rank.lower(ses.params, np.zeros((b, g, cfg.d_x), np.float32),
-                           np.zeros((b, cfg.d_q), np.float32),
-                           np.ones((b, g), np.float32),
-                           np.full((b,), 8.0, np.float32)).as_text()
+    batch = alloc_batch(BATCH, 8, cfg.d_x, cfg.d_q)
+    text = ses._rank.lower(ses.params, batch["buf"]).as_text()
     assert "@jit_cascade_rank" in text
 
 

@@ -15,7 +15,7 @@ from repro.core import losses as L
 from repro.core import pipeline as P
 from repro.core import trainer as T
 from repro.data import LogConfig, generate_log
-from repro.serving.batching import RankRequest
+from repro.serving.batching import RankRequest, alloc_batch
 from repro.serving.cascade_server import CascadeServer, NeuralScorer
 
 
@@ -95,9 +95,9 @@ def test_fused_kernel_path_matches_xla_path(trained):
     """The fused score+filter pipeline must reproduce the unfused XLA
     path EXACTLY: same survivor sets at every stage, same orderings."""
     params, cfg, lcfg, tr, te = trained
-    batch = {"x": te.x[:4].astype(np.float32), "q": te.q[:4].astype(np.float32),
-             "mask": te.mask[:4].astype(np.float32),
-             "m_q": te.m_q[:4].astype(np.float32)}
+    batch = alloc_batch(4, te.x.shape[1], cfg.d_x, cfg.d_q)
+    for k in ("x", "q", "mask", "m_q"):
+        batch[k][...] = getattr(te, k)[:4]
     a = CascadeServer(params, cfg, lcfg, fused="filter").rank_batch(batch)
     b = CascadeServer(params, cfg, lcfg, fused="none").rank_batch(batch)
     # identical survivor sets — final AND per-stage (the served result

@@ -26,6 +26,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import cascade as C
+from repro.core import losses as L
 from repro.core import pipeline as P
 from repro.data import features as F
 from repro.kernels import ops as K
@@ -33,6 +34,8 @@ from repro.kernels.cascade_filter.kernel import cascade_filter
 from repro.kernels.cascade_loss.kernel import cascade_loss, cascade_loss_bwd
 from repro.kernels.cascade_score.kernel import (cascade_score_batched,
                                                 cascade_score_batched_bwd)
+from repro.serving.batching import staging_width
+from repro.serving.session import CascadeSession, ServingConfig
 
 D_X, T = 24, 3
 
@@ -145,3 +148,29 @@ def test_run_cascade_filter_plan_compiles_at_4096(one_chip):
     """The whole serving pipeline at the wide ladder's largest bucket
     (cloes3_wide) and batch."""
     _compile_run_cascade(one_chip, 32, 4096)
+
+
+@pytest.mark.parametrize("g", [256, 4096])
+def test_serving_program_compiles_from_one_staging_buffer(one_chip, g,
+                                                          monkeypatch):
+    """The session's jit_cascade_rank, as a flush dispatches it: the
+    params and ONE (32, W) staging buffer, unpacked on the chip, at each
+    ladder's largest bucket. The session's pipeline asks the backend
+    whether to interpret its kernels; steered to the compiled kernel."""
+    monkeypatch.setattr(K, "_auto_interpret", lambda: False)
+    masks = F.default_stage_masks(T)
+    cfg = C.CascadeConfig(T, F.N_FEATURES, F.N_QUERY_BUCKETS, masks,
+                          F.stage_costs(masks))
+    params = C.init_params(cfg, jax.random.PRNGKey(0))
+    ses = CascadeSession(params, cfg, L.LossConfig(),
+                         scfg=ServingConfig(plan="filter",
+                                            group_buckets=(16, g),
+                                            batch_groups=32))
+    pspec = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    buf = jax.ShapeDtypeStruct((32, staging_width(g, cfg.d_x, cfg.d_q)),
+                               jnp.float32, sharding=one_chip)
+    lowered = ses._rank.lower(pspec, buf)
+    assert len(jax.tree_util.tree_leaves(lowered.args_info)) == \
+        len(jax.tree_util.tree_leaves(params)) + 1
+    assert kernel_names(lowered.compile().as_text()) == {"cascade_filter"}

@@ -1,19 +1,13 @@
-"""Shared benchmark infrastructure: dataset, trained models, timing."""
+"""Shared benchmark infrastructure: dataset, trained models, output."""
 
 from __future__ import annotations
 
-import time
 from functools import lru_cache
-
-import jax
-import numpy as np
 
 from repro.core import baselines as B
 from repro.core import losses as L
 from repro.core import trainer as T
 from repro.data import generate_log, LogConfig
-
-BASE_COST = None  # set by table3
 
 
 @lru_cache(maxsize=1)
@@ -42,18 +36,6 @@ def trained_cloes(beta: float = 5.0, delta: float = 1.0,
     params, cfg = B.fit_cloes(
         tr, lcfg=lcfg, tcfg=T.TrainConfig(loss=loss, epochs=6, lr=0.01))
     return params, cfg, lcfg
-
-
-def time_call(fn, *args, warmup: int = 2, iters: int = 10) -> float:
-    """Median wall-time in microseconds of a jax callable (block_until_ready)."""
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args))
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        ts.append(time.perf_counter() - t0)
-    return float(np.median(ts) * 1e6)
 
 
 def emit(name: str, us_per_call: float, derived: str):

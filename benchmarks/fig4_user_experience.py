@@ -18,6 +18,7 @@ from benchmarks.common import bench_split, emit, trained_cloes
 from repro.core import cascade as C
 from repro.core import losses as L
 from repro.core import metrics as M
+from repro.core import pipeline as P
 
 
 def _per_query(params, cfg, lcfg, te):
@@ -29,7 +30,7 @@ def _per_query(params, cfg, lcfg, te):
                                                     m_q))[:, -1]
     lat = np.asarray(L.expected_latency_per_query(params, cfg, lcfg, x, q,
                                                   mask, m_q))
-    res = C.hard_cascade_filter(params, cfg, x, q, mask, m_q)
+    res = P.run_cascade(params, cfg, x, q, mask, m_q, fused="none")
     scores = np.where(np.asarray(res["survivors"][..., -1]) > 0,
                       np.asarray(res["scores"]), -np.inf)
     sess = M.simulate_session(scores, te.relevance, te.price, te.mask, lat)

@@ -1,4 +1,5 @@
-"""Benchmark driver — one function per paper table/figure.
+"""The paper's tables and figures, reproduced on the synthetic log — one
+function each.
 
 Prints ``name,us_per_call,derived`` CSV rows. Run with:
     PYTHONPATH=src python -m benchmarks.run
@@ -13,19 +14,13 @@ import traceback
 
 def main() -> None:
     from benchmarks import (fig3_uninstall, fig4_user_experience,
-                            fig5_peak_load, kernel_bench, roofline_report,
-                            serving_bench, table3_offline, table4_importance,
-                            train_bench)
+                            fig5_peak_load, table3_offline, table4_importance)
     suites = [
         ("table3", table3_offline.run),
         ("table4", table4_importance.run),
         ("fig3", fig3_uninstall.run),
         ("fig4", fig4_user_experience.run),
         ("fig5", fig5_peak_load.run),
-        ("kernels", kernel_bench.run),
-        ("serving", serving_bench.run),
-        ("train", train_bench.run),
-        ("roofline", roofline_report.run),
     ]
     print("name,us_per_call,derived")
     failures = []

@@ -10,9 +10,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import bench_split, emit, trained_cloes
-from repro.core import cascade as C
 from repro.core import losses as L
 from repro.core import metrics as M
+from repro.core import pipeline as P
 
 
 def _online_metrics(params, cfg, lcfg, te, seed=0):
@@ -20,7 +20,7 @@ def _online_metrics(params, cfg, lcfg, te, seed=0):
     q = jnp.asarray(te.q, jnp.float32)
     mask = jnp.asarray(te.mask, jnp.float32)
     m_q = jnp.asarray(te.m_q, jnp.float32)
-    res = C.hard_cascade_filter(params, cfg, x, q, mask, m_q)
+    res = P.run_cascade(params, cfg, x, q, mask, m_q, fused="none")
     scores = np.where(np.asarray(res["survivors"][..., -1]) > 0,
                       np.asarray(res["scores"]), -np.inf)
     lat = np.asarray(L.expected_latency_per_query(

@@ -12,7 +12,7 @@ reference run at highest matmul precision. Phases:
   serve    plan "filter": warm all 18 shapes, serve requests in every
            bucket (16/64/256 items) through run_open_loop; every one comes
            back "ok" with no errors, faults or retries and no recompile
-           after warmup. Plan "score" once as well
+           after warmup
   compare  served lp and survivors against run_cascade(fused="none"),
            and the fused loss value and gradients against
            cascade_loss_ref
@@ -484,9 +484,8 @@ def run_one_chip(size: Size, seed: int) -> None:
     check_train_kernel(params, cfg, lcfg, tr, size, seed)
     reqs = make_requests(te, size, seed,
                          tuple(sorted(ServingConfig().group_buckets)))
-    for plan in ("filter", "score"):
-        resps = phase_serve(params, cfg, lcfg, reqs, plan, size, seed)
-        compare_serving(params, cfg, plan, reqs, resps)
+    resps = phase_serve(params, cfg, lcfg, reqs, "filter", size, seed)
+    compare_serving(params, cfg, "filter", reqs, resps)
     compare_loss(params, cfg, lcfg, tr, size, seed)
 
 

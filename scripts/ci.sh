@@ -1,9 +1,10 @@
 #!/usr/bin/env bash
 # CI entry point: fast loop first (fail fast on logic regressions), then
-# the full tier-1 suite, then the bench smoke legs. Every phase prints its
-# wall time; the fast loop FAILS if it exceeds its budget (ROADMAP
-# "Verification loops": the inner dev loop must stay fast — a budget breach
-# means tests need rebalancing onto the `slow` marker, not a bigger budget).
+# the full tier-1 suite, then the serving and restart smoke legs. Every
+# phase prints its wall time; the fast loop FAILS if it exceeds its budget
+# (ROADMAP "Verification loops": the inner dev loop must stay fast — a
+# budget breach means tests need rebalancing onto the `slow` marker, not a
+# bigger budget).
 #
 #   FAST_TIMEOUT / FULL_TIMEOUT   override the per-phase timeouts (seconds)
 #   FAST_BUDGET                   fast-loop wall-time budget (default 90 s;
@@ -60,18 +61,6 @@ if [[ "${SKIP_FULL:-0}" != "1" ]]; then
     timeout "${FULL_TIMEOUT:-900}" python -m pytest -x -q
     phase_done "full tier-1"
 fi
-
-echo "== train bench smoke: must run and write BENCH_train.json =="
-rm -f BENCH_train.json
-timeout "${BENCH_TIMEOUT:-300}" python -m benchmarks.train_bench --smoke
-test -s BENCH_train.json || { echo "BENCH_train.json missing"; exit 1; }
-phase_done "train bench smoke"
-
-echo "== serving bench smoke: must run and write BENCH_serving.json =="
-rm -f BENCH_serving.json
-timeout "${BENCH_TIMEOUT:-300}" python -m benchmarks.serving_bench --smoke
-test -s BENCH_serving.json || { echo "BENCH_serving.json missing"; exit 1; }
-phase_done "serving bench smoke"
 
 echo "== serve open-loop smoke: zero dropped futures + latency report =="
 # launch.serve exits nonzero itself if any submitted future never resolves

@@ -78,7 +78,7 @@ def executable_lines(path: str) -> set[int]:
 
 def main() -> int:
     # run from the repo root with the root importable, exactly like the CI
-    # pytest invocation (tests import the benchmarks package by name)
+    # pytest invocation
     os.chdir(_ROOT)
     if _ROOT not in sys.path:
         sys.path.insert(0, _ROOT)

@@ -15,7 +15,6 @@ are pure and jit-safe; shapes use the query-grouped batch layout
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Any
 
 import jax
@@ -125,30 +124,6 @@ def expected_counts_per_query(params: Params, cfg: CascadeConfig,
     return (m_q / n_q)[..., None] * pp.sum(axis=-2)
 
 
-@partial(jax.jit, static_argnames=("cfg",))
-def hard_cascade_filter(params: Params, cfg: CascadeConfig,
-                        x: jax.Array, q: jax.Array,
-                        mask: jax.Array, m_q: jax.Array) -> dict[str, jax.Array]:
-    """Run the cascade as deployed: per stage keep the top-E[Count_{q,j}]
-    items by cumulative score ('this expected number ... served as the
-    threshold for filtering out items in the corresponding stage').
-
-    Thin wrapper over core.pipeline.run_cascade — the single stage-filter
-    implementation shared with serving.CascadeServer.
-
-    Returns the survival mask after each stage (B, G, T), the final scores,
-    and the per-stage survivor counts actually used.
-    """
-    from repro.core import pipeline as P  # local: pipeline imports this module
-    out = P.run_cascade(params, cfg, x, q, mask, m_q, fused="none")
-    return {
-        "survivors": out["survivors"],                     # (B, G, T)
-        "scores": out["scores"],
-        "kept_per_stage": out["kept_per_stage"],           # (B, T)
-        "expected_counts": out["expected_counts"],
-    }
-
-
 def actual_cost_per_query(survivors: jax.Array, mask: jax.Array,
                           cfg: CascadeConfig) -> jax.Array:
     """Realized serving cost of the hard cascade, per query group:
@@ -157,3 +132,23 @@ def actual_cost_per_query(survivors: jax.Array, mask: jax.Array,
     entering = jnp.concatenate(
         [mask.sum(-1, keepdims=True), survivors.sum(1)[:, :-1]], axis=-1)  # (B, T)
     return (entering * t).sum(-1)
+
+
+def latency_from_counts(counts: jax.Array, m_q: jax.Array,
+                        cfg: CascadeConfig, latency_scale: float,
+                        convention: str = "entering") -> jax.Array:
+    """Eq-16 latency model from already-computed expected counts (B, T).
+
+    The one formula training (losses.latency_from_counts_q) and serving
+    (the pipeline's expected counts, in serving.session) both use:
+    sum_j t_j * E[Count], with 'entering' charging stage j for the items
+    entering it (Count_{q,0} = M_q) and 'paper' for those leaving it, as
+    Eq 16 is printed."""
+    t = jnp.asarray(cfg.t, dtype=counts.dtype)
+    if convention == "entering":
+        entering = jnp.concatenate(
+            [m_q[:, None].astype(counts.dtype), counts[:, :-1]], axis=-1)
+        lat = (entering * t).sum(-1)
+    else:  # as printed in the paper
+        lat = (counts * t).sum(-1)
+    return latency_scale * lat

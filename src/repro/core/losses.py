@@ -6,21 +6,20 @@ full L3 objective is a single scalar optimized by SGD (paper §3.2).
 
 Every objective derives from ONE shared cascade forward: `cascade_forward`
 computes the (B, G, T) cumulative log pass-probabilities once — through the
-same BATCHED fused scorer the serving pipeline uses
-(kernels.ops.cascade_score_batched, a custom-VJP Pallas kernel with a 2-D
-(batch, item-block) grid on TPU, the jitted XLA reference elsewhere) — plus
-the one stop-gradient variant L3's w_q-only penalty routing needs. NLL
-(Eq 4/17), expected cost (Eq 8), per-query counts (Eq 10) and the size and
-latency penalties (Eqs 14–16) are all cheap reductions of that tensor; the
-pre-refactor implementation re-scored the batch four times per L3 step.
+fused scorer (kernels.ops.cascade_score_batched, a custom-VJP Pallas
+kernel with a 2-D (batch, item-block) grid on TPU, the jitted XLA
+reference elsewhere) — plus the one stop-gradient variant L3's w_q-only
+penalty routing needs. NLL (Eq 4/17), expected cost (Eq 8), per-query
+counts (Eq 10) and the size and latency penalties (Eqs 14–16) are all
+cheap reductions of that tensor; the pre-refactor implementation
+re-scored the batch four times per L3 step.
 
-The deployed L3 objective goes one step further: by default it runs through
+The deployed L3 objective goes one step further: it runs through
 kernels.ops.cascade_loss_fused, which emits those per-item reductions from
 the SAME VMEM pass that computes the scores (and bakes the penalty
 stop-gradient routing into its VJP), collapsing the score-then-many-small-
-reductions step graph into one kernel launch — see _loss_l3_fused. The
-unfused graph stays reachable through loss_l3's score_fn seam (the trainer
-benchmark's baselines).
+reductions step graph into one kernel launch. The unfused graph is
+loss_l3_unfused, the reference the fused objective is tested against.
 
 Engine-batch protocol: every batch term that does not depend on the params
 is a pure function of (log, lcfg), so the scan trainer precomputes it ONCE
@@ -48,7 +47,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import cascade as C
-from repro.core.pipeline import latency_from_counts, resolve_plan
 from repro.data.synthetic import BEHAVIOR_CLICK, BEHAVIOR_PURCHASE
 from repro.kernels import ops as K
 from repro.kernels.cascade_loss.kernel import pack_items
@@ -93,18 +91,13 @@ class LossConfig:
 
 def cascade_forward(params: C.Params, cfg: C.CascadeConfig,
                     x: jax.Array, q: jax.Array, *,
-                    penalty_variant: bool = False,
-                    score_fn=None,
-                    plan: str = "score") -> tuple[jax.Array, jax.Array | None]:
+                    penalty_variant: bool = False
+                    ) -> tuple[jax.Array, jax.Array | None]:
     """(B, G, T) cumulative log pass-probabilities through the fused scorer.
 
-    x: (B, G, d_x), q: (B, d_q). The scorer is resolved through the
-    pipeline-plan registry (core.pipeline.PLANS — default plan "score":
-    kernels.ops.cascade_score_batched, one 2-D (batch, item-block) grid,
-    no jax.vmap wrapping), so training scores through the same registry
-    entry as serving; score_fn overrides it with any
-    (x, w_eff, zq) -> lp callable (the training benchmark pins the old
-    vmap-of-single-group path this way to measure the batched win).
+    x: (B, G, d_x), q: (B, d_q). Scores through
+    kernels.ops.cascade_score_batched: one 2-D (batch, item-block) grid,
+    no jax.vmap wrapping.
 
     With penalty_variant, also returns the stop-gradient routing L3's UX
     penalties need: the same primal values, but with w_x and b held
@@ -113,16 +106,15 @@ def cascade_forward(params: C.Params, cfg: C.CascadeConfig,
     variant re-runs only the scorer on already-computed inputs with the
     gradient taps moved, not a new loss formulation.
     """
-    score = score_fn or resolve_plan(plan).scorer
     masks = jnp.asarray(cfg.masks, dtype=x.dtype)
     w_eff = params["w_x"] * masks                                   # (T, d_x)
     zq = q @ params["w_q"].T + params["b"]                          # (B, T)
-    lp = score(x, w_eff, zq)
+    lp = K.cascade_score_batched(x, w_eff, zq)
     if not penalty_variant:
         return lp, None
     w_pen = jax.lax.stop_gradient(w_eff)
     zq_pen = q @ params["w_q"].T + jax.lax.stop_gradient(params["b"])
-    lp_pen = score(x, w_pen, zq_pen)
+    lp_pen = K.cascade_score_batched(x, w_pen, zq_pen)
     return lp, lp_pen
 
 
@@ -217,10 +209,10 @@ def latency_from_counts_q(counts: jax.Array, m_q, cfg: C.CascadeConfig,
                           lcfg: LossConfig) -> jax.Array:
     """E[Latency_{q,T}] = sum_j t_j * E[Count_{q,·}]  (Eq 16). Returns (B,).
 
-    Shares core.pipeline.latency_from_counts with the serving pipeline —
+    Shares core.cascade.latency_from_counts with the serving session —
     training and serving estimate latency from counts with the same code.
     """
-    return latency_from_counts(counts, m_q, cfg, lcfg.latency_scale,
+    return C.latency_from_counts(counts, m_q, cfg, lcfg.latency_scale,
                                lcfg.latency_convention)
 
 
@@ -325,17 +317,28 @@ def _l3_tail(params, cfg: C.CascadeConfig, lcfg: LossConfig,
             + lcfg.delta * size_pen + lcfg.eps_latency * lat_pen)
 
 
-def _loss_l3_fused(params, cfg: C.CascadeConfig, lcfg: LossConfig,
-                   batch) -> jax.Array:
-    """L3 through ONE kernels.ops.cascade_loss_fused call.
+def loss_l3(params, cfg: C.CascadeConfig, lcfg: LossConfig,
+            batch) -> jax.Array:
+    """The deployed CLOES objective (Eq 15), through ONE
+    kernels.ops.cascade_loss_fused call.
+
+    Gradient routing: the two user-experience penalties adjust only the
+    query-only parameters w_q. The paper states the query-only feature
+    "is used to control the magnitude of the prediction probability (thus to
+    control the result number and cost per query) but does not affect the
+    rank order". Letting the penalties push the *item* weights w_x (or the
+    global bias b, which the cost term then fights via w_x) saturates
+    tail-query probabilities and inverts within-query ordering — so w_x and b
+    are stop-gradient'd inside the penalty terms: per-query size/latency
+    control lives entirely in the per-recall-bucket weights w_q.
 
     The op computes the logits once and emits the three per-group partial
     reductions (NLL terms, Eq-8 cost accumulators, Eq-10 keep counts) in
     the same VMEM pass — everything left here is O(B*T). The Eq-15
-    stop-gradient routing (penalties adjust only w_q — see loss_l3) is
-    baked into the op's VJP: zq_pen is the gradient tap the counts stream
-    flows into, so the value-identical penalty-variant re-scoring pass of
-    the unfused graph disappears entirely.
+    stop-gradient routing above is baked into the op's VJP: zq_pen is the
+    gradient tap the counts stream flows into, so the value-identical
+    penalty-variant re-scoring pass of the unfused graph disappears
+    entirely.
 
     Engine batches (trainer._engine_pack) arrive with the wgt/cost_w/mn/
     n_o_eff columns precomputed AND the packed [x | y | mask | wgt |
@@ -373,33 +376,15 @@ def _loss_l3_fused(params, cfg: C.CascadeConfig, lcfg: LossConfig,
     return _l3_tail(params, cfg, lcfg, nll, cost, counts_pen, m_q, n_o)
 
 
-def loss_l3(params, cfg: C.CascadeConfig, lcfg: LossConfig, batch,
-            *, score_fn=None) -> jax.Array:
-    """The deployed CLOES objective (Eq 15).
-
-    Gradient routing: the two user-experience penalties adjust only the
-    query-only parameters w_q. The paper states the query-only feature
-    "is used to control the magnitude of the prediction probability (thus to
-    control the result number and cost per query) but does not affect the
-    rank order". Letting the penalties push the *item* weights w_x (or the
-    global bias b, which the cost term then fights via w_x) saturates
-    tail-query probabilities and inverts within-query ordering — so w_x and b
-    are stop-gradient'd inside the penalty terms: per-query size/latency
-    control lives entirely in the per-recall-bucket weights w_q.
-
-    By default (score_fn=None) the whole objective runs through ONE
-    kernels.ops.cascade_loss_fused call (see _loss_l3_fused): the scoring
-    pass and every per-item reduction fuse into a single kernel with the
-    stop-gradient routing in its VJP. Passing score_fn pins the unfused
-    score-then-reduce graph below (both penalties reducing the shared
-    penalty-variant forward lp_pen) with that scorer — the trainer
-    benchmark's loop/vmap/batched baselines live behind this seam.
-    """
-    if score_fn is None:
-        return _loss_l3_fused(params, cfg, lcfg, batch)
+def loss_l3_unfused(params, cfg: C.CascadeConfig, lcfg: LossConfig,
+                    batch) -> jax.Array:
+    """loss_l3 as the score-then-reduce graph: the scorer's shared forward
+    and its penalty variant (both penalties reducing lp_pen), then the
+    NLL, Eq-8 cost and Eq-15 tail reductions one by one. The reference the
+    fused objective must match in value and gradients; not a training
+    option."""
     x, q, mask, m_q = batch["x"], batch["q"], batch["mask"], batch["m_q"]
-    lp, lp_pen = cascade_forward(params, cfg, x, q, penalty_variant=True,
-                                 score_fn=score_fn)
+    lp, lp_pen = cascade_forward(params, cfg, x, q, penalty_variant=True)
     counts_pen = counts_from_lp(lp_pen, mask, m_q, batch.get("mn"))  # (B, T)
     n_o = batch.get("n_o_eff")
     if n_o is None:

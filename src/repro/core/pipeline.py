@@ -1,118 +1,40 @@
-"""The serving-time hard cascade — ONE implementation, shared by
-`core.cascade.hard_cascade_filter` and `serving.CascadeServer`.
+"""The serving-time hard cascade — ONE implementation, shared by the
+serving session and the offline tables.
 
 The paper's deployed system (§4, Eq 10) runs T chained stage filters:
 stage j keeps the top-E[Count_{q,j}] surviving items by cumulative
-score. Before this module, core and serving each carried their own
-copy of that stage loop (a double argsort per stage); both now call
-`run_cascade`, which routes either through the fused Pallas
-score+filter kernel (one VMEM pass per query group — see
-kernels/cascade_filter/kernel.py) or through the XLA stage chain
-below.
+score. `run_cascade` runs them in one of two plans:
 
-All functions are pure and jit-safe; `run_cascade` is the body that
-CascadeServer jits end-to-end per shape bucket.
+    'filter' — the fused Pallas score+filter kernel, one VMEM pass per
+               query group (kernels/cascade_filter/kernel.py): what the
+               service runs;
+    'none'   — the XLA reference: the reference scorer, then the
+               keep-count and stage-chain semantics of
+               kernels/cascade_filter/ref.py, which the tests and the
+               chip smoke compare the kernel against.
+
+All functions are pure and jit-safe; `run_cascade` is the body the
+serving session jits end-to-end per shape bucket.
 """
 
 from __future__ import annotations
-
-import dataclasses
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import cascade as C
 from repro.kernels import ops as K
+from repro.kernels.cascade_filter.ref import filter_chain, keep_counts_from_lp
+
+PLAN_NAMES = ("filter", "none")
 
 
-# ---------------------------------------------------------------------------
-# The pipeline-plan registry — THE single source of truth for serving-mode
-# resolution. Every consumer (run_cascade, losses.cascade_forward's scorer
-# seam, serving.CascadeSession / CascadeServer, the benches) resolves its
-# mode string through resolve_plan, so an unknown plan fails with the SAME
-# error everywhere and no module carries its own mode validation.
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class PipelinePlan:
-    """One named way to execute the cascade.
-
-    scorer: (x (B, G, d), w_eff (T, d), zq (B, T), *, interpret=None)
-            -> lp (B, G, T) — the shared scoring entry point this plan uses
-            (losses.cascade_forward scores through it too).
-    fused_filter: run the fully fused score+filter kernel instead of
-            scorer + the XLA stage chain.
-    """
-    name: str
-    description: str
-    scorer: Callable[..., jax.Array]
-    fused_filter: bool = False
-
-
-def _score_ref(x, w_eff, zq, *, interpret=None):
-    del interpret  # the XLA reference has no kernel body to interpret
-    return K.cascade_score_batched_ref(x, w_eff, zq)
-
-
-PLANS: dict[str, PipelinePlan] = {
-    "none": PipelinePlan(
-        "none", "XLA reference scorer + XLA stage chain", _score_ref),
-    "score": PipelinePlan(
-        "score", "batched fused Pallas scorer + XLA stage chain",
-        K.cascade_score_batched),
-    "filter": PipelinePlan(
-        "filter", "fully fused score+filter kernel (one VMEM pass)",
-        K.cascade_score_batched, fused_filter=True),
-}
-
-# Back-compat alias (pre-registry modules iterated this tuple).
-FUSED_MODES = tuple(PLANS)
-
-
-def resolve_plan(name: str) -> PipelinePlan:
-    """Resolve a plan name, raising the one shared unknown-plan error."""
-    plan = PLANS.get(name)
-    if plan is None:
-        raise ValueError(f"unknown pipeline plan: {name!r} "
-                         f"(expected one of {tuple(PLANS)})")
-    return plan
-
-
-def keep_counts_from_lp(lp: jax.Array, mask: jax.Array,
-                        m_q: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Eq-10 expected counts and per-stage keep counts from cumulative log
-    pass-probs. lp: (B, G, T), mask: (B, G), m_q: (B,) -> ((B, T), (B, T)).
-
-    Keep counts are the expected counts rescaled from the M_q recalled
-    items to the G scored items, bounded by [1, G]."""
-    g = mask.shape[-1]
-    maskf = mask.astype(jnp.float32)
-    n_q = jnp.maximum(maskf.sum(-1), 1.0)
-    pp = jnp.exp(lp) * maskf[..., None]
-    counts = (m_q.astype(jnp.float32) / n_q)[:, None] * pp.sum(-2)
-    n_keep = jnp.clip(
-        jnp.ceil(counts * maskf.sum(-1, keepdims=True)
-                 / jnp.maximum(m_q[:, None].astype(jnp.float32), 1.0)),
-        1.0, float(g))
-    return counts, n_keep
-
-
-def filter_chain(lp: jax.Array, mask: jax.Array,
-                 n_keep: jax.Array) -> jax.Array:
-    """XLA stage chain: per stage, stable top-n_keep of the current
-    survivors by lp[..., j] ('this expected number ... served as the
-    threshold for filtering out items in the corresponding stage').
-
-    Returns the per-stage survivor masks (B, G, T)."""
-    surv = mask.astype(jnp.float32)
-    cols = []
-    for j in range(lp.shape[-1]):
-        s = jnp.where(surv > 0, lp[..., j], -jnp.inf)
-        rank = jnp.argsort(jnp.argsort(-s, axis=-1), axis=-1).astype(jnp.float32)
-        surv = surv * (rank < n_keep[:, j:j + 1]).astype(jnp.float32)
-        cols.append(surv)
-    return jnp.stack(cols, axis=-1)
+def check_plan(plan: str) -> None:
+    """The one unknown-plan error, raised by run_cascade and by the
+    serving session at construction."""
+    if plan not in PLAN_NAMES:
+        raise ValueError(f"unknown pipeline plan: {plan!r} "
+                         f"(expected one of {PLAN_NAMES})")
 
 
 def run_cascade(params: C.Params, cfg: C.CascadeConfig,
@@ -121,17 +43,15 @@ def run_cascade(params: C.Params, cfg: C.CascadeConfig,
                 interpret: bool | None = None) -> dict[str, jax.Array]:
     """Score + hard-filter a padded (B, G) candidate batch.
 
-    fused names a PLANS entry:
-           'none'   — XLA scorer + XLA stage chain (the reference path);
-           'score'  — batched fused Pallas scorer, XLA stage chain;
-           'filter' — fully fused score+filter kernel (one VMEM pass).
+    fused names the plan: 'filter' (the fused kernel) or 'none' (the XLA
+    reference scorer and stage chain).
 
     Returns lp (B, G, T), survivors (B, G, T), scores (B, G),
     expected_counts (B, T), n_keep (B, T), kept_per_stage (B, T)."""
-    # Resolve the plan BEFORE any compute: an unknown plan must not cost
+    # Check the plan BEFORE any compute: an unknown plan must not cost
     # a scoring setup (w_eff/zq) or surface as a downstream shape error.
-    plan = resolve_plan(fused)
-    # One scoring formulation for every mode (precomputed w_eff / zq, the
+    check_plan(fused)
+    # One scoring formulation for both plans (precomputed w_eff / zq, the
     # kernel's decomposition): the fused and unfused paths must agree not
     # just to tolerance but on every DISCRETE decision (ceil'd keep
     # counts, tie-breaks), which only holds if they run the same float
@@ -141,12 +61,12 @@ def run_cascade(params: C.Params, cfg: C.CascadeConfig,
     w_eff = params["w_x"] * jnp.asarray(cfg.masks, jnp.float32)
     zq = (jnp.matmul(q, params["w_q"].T, precision=jax.lax.Precision.HIGHEST)
           + params["b"])
-    if plan.fused_filter:
+    if fused == "filter":
         out = K.cascade_filter(x, w_eff, zq, mask, m_q, interpret=interpret)
         lp, surv = out["lp"], out["survivors"]
         counts, n_keep = out["expected_counts"], out["n_keep"]
     else:
-        lp = plan.scorer(x, w_eff, zq, interpret=interpret)
+        lp = K.cascade_score_batched_ref(x, w_eff, zq)
         counts, n_keep = keep_counts_from_lp(lp, mask, m_q)
         surv = filter_chain(lp, mask, n_keep)
     return {
@@ -157,19 +77,3 @@ def run_cascade(params: C.Params, cfg: C.CascadeConfig,
         "n_keep": n_keep,
         "kept_per_stage": surv.sum(1),
     }
-
-
-def latency_from_counts(counts: jax.Array, m_q: jax.Array,
-                        cfg: C.CascadeConfig, latency_scale: float,
-                        convention: str = "entering") -> jax.Array:
-    """Eq-16 latency model from already-computed expected counts (B, T) —
-    the serving pipeline's latency estimate without re-scoring the batch
-    (cf. losses.expected_latency_per_query, which scores from params)."""
-    t = jnp.asarray(cfg.t, dtype=counts.dtype)
-    if convention == "entering":
-        entering = jnp.concatenate(
-            [m_q[:, None].astype(counts.dtype), counts[:, :-1]], axis=-1)
-        lat = (entering * t).sum(-1)
-    else:  # as printed in the paper
-        lat = (counts * t).sum(-1)
-    return latency_scale * lat

@@ -40,6 +40,7 @@ from jax.sharding import Mesh, PartitionSpec as PS
 from repro.checkpoint import CheckpointStore
 from repro.core import cascade as C
 from repro.core import losses as L
+from repro.core import metrics as M
 from repro.data.synthetic import SearchLog
 from repro.kernels.cascade_loss.kernel import pack_items
 from repro.optim.sgd import apply_updates, momentum_sgd
@@ -439,7 +440,6 @@ def evaluate(params: C.Params, cfg: C.CascadeConfig, log: SearchLog,
     from the same (B, G, T) log pass-probabilities (the pre-refactor
     version re-scored the log four times).
     """
-    from repro.core import metrics as M
     lcfg = lcfg or L.LossConfig()
     x = jnp.asarray(log.x, jnp.float32)
     q = jnp.asarray(log.q, jnp.float32)

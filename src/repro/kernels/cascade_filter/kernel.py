@@ -38,7 +38,7 @@ The kernel instead finds the THRESHOLD by counting:
     counting search over the item index (1 pass to G_pad = 256, 3 at
     4096). That is the stable argsort's tie-break, so the survivor
     sets are bit-identical to the unfused path's double argsort
-    (core.pipeline.filter_chain), ties included.
+    (ref.filter_chain), ties included.
   * fewer alive items than n_keep: no candidate reaches n_keep, tau
     stays below every key, and all alive items survive, as there.
 
@@ -160,7 +160,7 @@ def _kernel(x_ref, w_ref, zq_ref, mask_ref, mq_ref,
     valid = mask_ref[0].astype(jnp.float32)             # (1, G_pad)
     m_q = mq_ref[0].astype(jnp.float32)                 # (1, 1)
 
-    # -- fused scorer (same math as cascade_score): one MXU matmul ---------
+    # -- fused scorer (the scorer kernel's math): one MXU matmul ---------
     logits = jax.lax.dot_general(
         w, x, (((1,), (1,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32) + zq        # (T_pad, G_pad)

@@ -9,19 +9,54 @@ Semantics (must stay bit-compatible with kernel.py):
     surv_j      = top-n_keep[j] of surv_{j-1} by lp[., j], STABLE
                   descending order (ties keep the lowest index)
 
-The keep-count and stage-chain semantics are core.pipeline's
-keep_counts_from_lp / filter_chain — imported, not copied, since this
-function doubles as the production non-TPU path and must never fork
-from the pipeline. filter_chain's stable top-k is the double argsort,
-the very construct the kernel replaces with a counting select, so the
-parity sweeps still compare two algorithmically independent
-formulations of the selection.
+keep_counts_from_lp and filter_chain are those keep-count and stage-chain
+semantics, defined once here: core.pipeline's XLA reference plan runs
+them too, so the production non-TPU path never forks from the pipeline.
+filter_chain's stable top-k is the double argsort, the very construct
+the kernel replaces with a counting select, so the parity sweeps still
+compare two algorithmically independent formulations of the selection.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+
+def keep_counts_from_lp(lp: jax.Array, mask: jax.Array,
+                        m_q: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Eq-10 expected counts and per-stage keep counts from cumulative log
+    pass-probs. lp: (B, G, T), mask: (B, G), m_q: (B,) -> ((B, T), (B, T)).
+
+    Keep counts are the expected counts rescaled from the M_q recalled
+    items to the G scored items, bounded by [1, G]."""
+    g = mask.shape[-1]
+    maskf = mask.astype(jnp.float32)
+    n_q = jnp.maximum(maskf.sum(-1), 1.0)
+    pp = jnp.exp(lp) * maskf[..., None]
+    counts = (m_q.astype(jnp.float32) / n_q)[:, None] * pp.sum(-2)
+    n_keep = jnp.clip(
+        jnp.ceil(counts * maskf.sum(-1, keepdims=True)
+                 / jnp.maximum(m_q[:, None].astype(jnp.float32), 1.0)),
+        1.0, float(g))
+    return counts, n_keep
+
+
+def filter_chain(lp: jax.Array, mask: jax.Array,
+                 n_keep: jax.Array) -> jax.Array:
+    """XLA stage chain: per stage, stable top-n_keep of the current
+    survivors by lp[..., j] ('this expected number ... served as the
+    threshold for filtering out items in the corresponding stage').
+
+    Returns the per-stage survivor masks (B, G, T)."""
+    surv = mask.astype(jnp.float32)
+    cols = []
+    for j in range(lp.shape[-1]):
+        s = jnp.where(surv > 0, lp[..., j], -jnp.inf)
+        rank = jnp.argsort(jnp.argsort(-s, axis=-1), axis=-1).astype(jnp.float32)
+        surv = surv * (rank < n_keep[:, j:j + 1]).astype(jnp.float32)
+        cols.append(surv)
+    return jnp.stack(cols, axis=-1)
 
 
 @jax.jit
@@ -31,8 +66,6 @@ def cascade_filter_ref(x: jax.Array, w_eff: jax.Array, zq: jax.Array,
 
     Returns the same dict as kernel.cascade_filter.
     """
-    # local import: kernels.ops -> this module; core.pipeline -> kernels.ops
-    from repro.core.pipeline import filter_chain, keep_counts_from_lp
     logits = (jnp.einsum("bgd,td->bgt", x.astype(jnp.float32),
                          w_eff.astype(jnp.float32))
               + zq.astype(jnp.float32)[:, None, :])

@@ -41,7 +41,7 @@ lane width), so the in-kernel matmul over the FULL packed width produces
 logits bit-identical to an x-only matmul — zero weight times finite data
 is exactly zero — and the data columns are recovered by static lane slices.
 Callers without an engine batch concatenate the four columns on the fly
-(one cheap concat; see losses._loss_l3_fused).
+(one cheap concat; see losses.loss_l3).
 
 Layout and padding contract (mirrors kernels/cascade_score — forward and
 backward identically):
@@ -97,7 +97,7 @@ def pack_items(x, y, mask, wgt, cost_w):
     """THE packed-item layout: [x | y | mask | wgt | cost_w] along the
     feature axis. Single definition of the column order the kernels and
     the XLA ref slice by — trainer._engine_pack and the raw-batch path in
-    losses._loss_l3_fused both pack through here."""
+    losses.loss_l3 both pack through here."""
     return jnp.concatenate(
         [x, y[..., None], mask[..., None], wgt[..., None],
          cost_w[..., None]], axis=-1)

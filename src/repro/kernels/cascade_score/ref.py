@@ -1,14 +1,14 @@
 """Pure-jnp oracle for the fused cascade scorer.
 
-Computes, for every item, the per-stage cumulative log pass-probability of
-the CLOES cascade (Eqs 1-2, 6):
+Computes, for every item of every query group, the per-stage cumulative
+log pass-probability of the CLOES cascade (Eqs 1-2, 6):
 
-    logit[i, j] = x[i] . w_eff[j] + zq[j]
-    out[i, j]   = sum_{k<=j} log sigmoid(logit[i, k])
+    logit[b, i, j] = x[b, i] . w_eff[j] + zq[b, j]
+    out[b, i, j]   = sum_{k<=j} log sigmoid(logit[b, i, k])
 
 w_eff is the stage weight vector already masked by the stage feature mask;
-zq[j] = w_q[j] . g(q) + b[j] is the per-stage query-side bias (scalar per
-stage for a given query).
+zq[b, j] = w_q[j] . g(q_b) + b[j] is the per-stage query-side bias of
+group b.
 """
 
 from __future__ import annotations
@@ -17,41 +17,14 @@ import jax
 import jax.numpy as jnp
 
 
-def cascade_score_ref(x: jax.Array, w_eff: jax.Array,
-                      zq: jax.Array) -> jax.Array:
-    """x: (N, d), w_eff: (T, d), zq: (T,). Returns (N, T) f32."""
-    logits = (x.astype(jnp.float32) @ w_eff.astype(jnp.float32).T
-              + zq.astype(jnp.float32))
-    return jnp.cumsum(jax.nn.log_sigmoid(logits), axis=-1)
-
-
 def cascade_score_batched_ref(x: jax.Array, w_eff: jax.Array,
                               zq: jax.Array) -> jax.Array:
-    """Batched oracle: x (B, G, d), w_eff (T, d), zq (B, T) -> (B, G, T).
+    """x (B, G, d), w_eff (T, d), zq (B, T) -> (B, G, T) f32.
 
-    The per-(batch, item) math is cascade_score_ref's exactly — this is
-    both the parity oracle for the batched Pallas kernel and the
+    Both the parity oracle for the batched Pallas kernel and the
     production non-TPU path (natively autodiff-able, see kernels/ops.py).
     """
     logits = (jnp.einsum("bgd,td->bgt", x.astype(jnp.float32),
                          w_eff.astype(jnp.float32))
               + zq.astype(jnp.float32)[:, None, :])
     return jnp.cumsum(jax.nn.log_sigmoid(logits), axis=-1)
-
-
-def cascade_score_bwd_ref(x: jax.Array, w_eff: jax.Array, zq: jax.Array,
-                          g: jax.Array) -> tuple[jax.Array, jax.Array,
-                                                 jax.Array]:
-    """Closed-form backward of `cascade_score_ref` — the XLA oracle the
-    Pallas backward kernel mirrors (see kernel.py for the derivation).
-
-    g: (N, T) cotangent of the cumulative log pass-probs.
-    Returns (dx (N, d), dw_eff (T, d), dzq (T,)), all f32.
-    """
-    xf = x.astype(jnp.float32)
-    wf = w_eff.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
-    logits = xf @ wf.T + zq.astype(jnp.float32)
-    gc = gf.sum(axis=-1, keepdims=True) - jnp.cumsum(gf, axis=-1) + gf
-    g_logit = gc * jax.nn.sigmoid(-logits)                 # (N, T)
-    return g_logit @ wf, g_logit.T @ xf, g_logit.sum(axis=0)

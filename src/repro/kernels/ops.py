@@ -25,14 +25,9 @@ from repro.kernels.cascade_loss.kernel import (
 from repro.kernels.cascade_loss.ref import (cascade_loss_bwd_ref,
                                             cascade_loss_ref)
 from repro.kernels.cascade_score.kernel import (
-    cascade_score as _cascade_score,
     cascade_score_batched as _cascade_score_batched,
-    cascade_score_batched_bwd as _cascade_score_batched_bwd,
-    cascade_score_bwd as _cascade_score_bwd,
-    cascade_score_fm as _cascade_score_fm)
-from repro.kernels.cascade_score.ref import (cascade_score_batched_ref,
-                                             cascade_score_bwd_ref,
-                                             cascade_score_ref)
+    cascade_score_batched_bwd as _cascade_score_batched_bwd)
+from repro.kernels.cascade_score.ref import cascade_score_batched_ref
 from repro.kernels.swa_decode.kernel import swa_decode as _swa_decode, NO_WINDOW
 from repro.kernels.swa_decode.ref import swa_decode_ref
 
@@ -54,30 +49,13 @@ def _require_ranks(op: str, **named) -> None:
 
 
 # ---------------------------------------------------------------------------
-# cascade_score is differentiable, so training scores through the SAME op
-# as serving. The Pallas path carries a custom VJP (autodiff cannot see
+# cascade_score_batched is differentiable: the training losses score
+# through it. The Pallas path carries a custom VJP (autodiff cannot see
 # through pallas_call) whose backward is itself a fused Pallas kernel; the
 # XLA reference on non-TPU backends is natively autodiff-able, and wrapping
 # it in the custom VJP would only block XLA's cross-term fusion/CSE of the
 # training graph (measured ~25% slower L3 steps on CPU).
 # ---------------------------------------------------------------------------
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _cascade_score_pallas(interpret, x, w_eff, zq):
-    return _cascade_score(x, w_eff, zq, interpret=interpret)
-
-
-def _cascade_score_fwd(interpret, x, w_eff, zq):
-    return _cascade_score_pallas(interpret, x, w_eff, zq), (x, w_eff, zq)
-
-
-def _cascade_score_bwd_rule(interpret, res, g):
-    x, w_eff, zq = res
-    return _cascade_score_bwd(x, w_eff, zq, g, interpret=interpret)
-
-
-_cascade_score_pallas.defvjp(_cascade_score_fwd, _cascade_score_bwd_rule)
-
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _cascade_score_batched_pallas(interpret, x, w_eff, zq):
@@ -106,7 +84,7 @@ _cascade_score_batched_pallas.defvjp(_cascade_score_batched_fwd,
 # zq_pen only. The XLA ref rides plain autodiff — same policy (and same
 # measured reason: ~20% slower L3 steps with a VJP boundary, which blocks
 # XLA from fusing the backward into the forward's loop fusions) as the
-# plain scorer — with the identical routing expressed algebraically inside
+# scorer — with the identical routing expressed algebraically inside
 # the ref (exact-Jacobian zq_pen tap; see kernels/cascade_loss/ref.py).
 # ---------------------------------------------------------------------------
 
@@ -162,33 +140,17 @@ def cascade_loss_fused(xc, w_eff, zq, zq_pen=None, *,
                                 zq if zq_pen is None else zq_pen)
 
 
-def cascade_score(x, w_eff, zq, *, interpret: bool | None = None):
-    """Fused T-stage cascade scoring: (N, d) items -> (N, T) cumulative
-    log pass-probabilities. See kernels/cascade_score/kernel.py.
-
-    Differentiable on every path — custom VJP with a fused Pallas backward
-    kernel around the compiled/interpreted kernel, plain autodiff through
-    the jitted XLA reference on non-TPU backends — so the training losses
-    score through the same op as the serving pipeline. interpret=True
-    forces the Pallas interpreter on both passes (parity tests)."""
-    _require_ranks("cascade_score", x=(x, 2), w_eff=(w_eff, 2), zq=(zq, 1))
-    if interpret is None:
-        if _auto_interpret():
-            return cascade_score_ref(x, w_eff, zq)
-        interpret = False
-    return _cascade_score_pallas(interpret, x, w_eff, zq)
-
-
 def cascade_score_batched(x, w_eff, zq, *, interpret: bool | None = None):
-    """Batched fused scorer: x (B, G, d) padded query groups, w_eff (T, d),
+    """The fused scorer: x (B, G, d) padded query groups, w_eff (T, d),
     zq (B, T) per-group biases -> (B, G, T) cumulative log pass-probs.
+    See kernels/cascade_score/kernel.py.
 
-    THE shared serving/training scoring entry point (core.pipeline
-    fused="score", losses.cascade_forward, CascadeServer): a native 2-D
-    (batch, item-block) grid with no jax.vmap wrapping of the kernel.
-    Differentiable on every path — custom VJP with the batched Pallas
-    backward kernel on TPU/interpret, plain autodiff through the batched
-    XLA reference elsewhere."""
+    The training losses score through it (losses.cascade_forward): a
+    native 2-D (batch, item-block) grid with no jax.vmap wrapping of the
+    kernel. Differentiable on every path — custom VJP with the Pallas
+    backward kernel on TPU/interpret, plain autodiff through the XLA
+    reference elsewhere. interpret=True forces the Pallas interpreter on
+    both passes (parity tests)."""
     _require_ranks("cascade_score_batched",
                    x=(x, 3), w_eff=(w_eff, 2), zq=(zq, 2))
     if interpret is None:
@@ -196,18 +158,6 @@ def cascade_score_batched(x, w_eff, zq, *, interpret: bool | None = None):
             return cascade_score_batched_ref(x, w_eff, zq)
         interpret = False
     return _cascade_score_batched_pallas(interpret, x, w_eff, zq)
-
-
-def cascade_score_fm(xt, w_eff, zq, *, interpret: bool | None = None):
-    """Feature-major fused scorer: xt (d, N) -> (N, T). The production
-    layout — see kernels/cascade_score/kernel.py."""
-    _require_ranks("cascade_score_fm", xt=(xt, 2), w_eff=(w_eff, 2),
-                   zq=(zq, 1))
-    if interpret is None:
-        if _auto_interpret():
-            return cascade_score_ref(xt.T, w_eff, zq)
-        interpret = False
-    return _cascade_score_fm(xt, w_eff, zq, interpret=interpret)
 
 
 def cascade_filter(x, w_eff, zq, mask, m_q, *, interpret: bool | None = None):
@@ -238,7 +188,6 @@ def swa_decode(q, k, v, cache_len, *, window: int = NO_WINDOW,
 
 
 __all__ = ["cascade_loss_fused", "cascade_loss_ref", "cascade_loss_bwd_ref",
-           "cascade_score", "cascade_score_batched",
-           "cascade_score_batched_ref", "cascade_score_fm",
-           "cascade_score_ref", "cascade_score_bwd_ref", "cascade_filter",
-           "cascade_filter_ref", "swa_decode", "swa_decode_ref", "NO_WINDOW"]
+           "cascade_score_batched", "cascade_score_batched_ref",
+           "cascade_filter", "cascade_filter_ref", "swa_decode",
+           "swa_decode_ref", "NO_WINDOW"]

@@ -46,6 +46,7 @@ from repro.checkpoint import load_pytree, save_pytree
 from repro.core import baselines as B
 from repro.core import cascade as C
 from repro.core import losses as L
+from repro.core import pipeline as P
 from repro.core import trainer as T
 from repro.data import LogConfig, generate_log
 from repro.launch.compile_cache import enable_compile_cache
@@ -222,8 +223,9 @@ def main() -> None:
                     help="chaos mode: injected-fault rate (transient "
                          "exceptions, latency spikes, NaN corruption, "
                          "poison requests; 0 = off)")
-    ap.add_argument("--plan", default="filter",
-                    help="pipeline plan (core.pipeline.PLANS entry)")
+    ap.add_argument("--plan", default="filter", choices=P.PLAN_NAMES,
+                    help="pipeline plan: the fused kernel or the XLA "
+                         "reference")
     ap.add_argument("--neural", default="",
                     help="arch id for the neural final stage (smoke variant)")
     ap.add_argument("--beta", type=float, default=5.0)

@@ -107,16 +107,15 @@ class CascadeServer:
                  use_fused_kernel: bool | None = None,
                  fused: str | None = None,
                  batcher: RequestBatcher | None = None):
-        # fused names a core.pipeline.PLANS entry directly ('filter' — the
-        # fully fused kernel, 'score' — the batched scorer + XLA stage
-        # chain, 'none' — the XLA reference path). use_fused_kernel is the
-        # pre-registry bool API, deprecated for one release of aliasing;
+        # fused names the pipeline plan directly ('filter' — the fully
+        # fused kernel, 'none' — the XLA reference path). use_fused_kernel
+        # is the older bool API, deprecated for one release of aliasing;
         # an explicit fused= always takes precedence over the legacy bool.
         if use_fused_kernel is not None:
             warnings.warn(
                 "CascadeServer(use_fused_kernel=...) is deprecated; pass "
                 "fused='filter' (True) or fused='none' (False) — a "
-                "core.pipeline.PLANS plan name — instead",
+                "pipeline plan name — instead",
                 DeprecationWarning, stacklevel=2)
             if fused is None:
                 fused = "filter" if use_fused_kernel else "none"

@@ -28,7 +28,7 @@ module makes the API:
   degradation applied to a request is recorded on its response.
 
 The compute core is the same ONE jitted pipeline CascadeServer always ran
-(core.pipeline.run_cascade through the plan registry + optional neural
+(core.pipeline.run_cascade in the configured plan + optional neural
 final stage + Eq-16 latency); CascadeServer itself is now a thin
 compatibility shim over this engine, and with shedding/degradation
 disabled a submit-all-then-flush() session is bit-identical to
@@ -130,7 +130,8 @@ class ServingConfig:
     """ONE configuration surface for the serving engine (replaces the
     accreted per-call kwargs: use_fused_kernel/fused/batcher/neural_cost).
 
-    plan names a core.pipeline.PLANS entry; the batcher geometry mirrors
+    plan is 'filter' (the fused kernel) or 'none' (the XLA reference,
+    core.pipeline.PLAN_NAMES); the batcher geometry mirrors
     RequestBatcher's defaults; max_queue=None keeps the legacy unbounded
     queue (the CascadeServer shim's compatibility mode)."""
     plan: str = "filter"
@@ -308,10 +309,10 @@ class CascadeSession:
         # Optional chaos hook: a seeded FaultInjector wrapping the execute
         # seam (faults=None keeps the serving path bit-identical).
         self.faults = faults
-        # Resolve the plan at construction — unknown plans must fail here,
-        # with the registry's one shared error, not from inside the first
+        # Check the plan at construction — unknown plans must fail here,
+        # with run_cascade's one shared error, not from inside the first
         # rank_batch trace.
-        P.resolve_plan(self.scfg.plan)
+        P.check_plan(self.scfg.plan)
         self.buckets = tuple(sorted(self.scfg.group_buckets))
         if pipeline_from is not None:
             # Simulated co-located replicas share ONE warmed jit cache:
@@ -421,7 +422,7 @@ class CascadeSession:
 
             # Eq-16 latency from the pipeline's own expected counts — no
             # re-scoring of the batch.
-            lat = P.latency_from_counts(out["expected_counts"], m_q, self.cfg,
+            lat = C.latency_from_counts(out["expected_counts"], m_q, self.cfg,
                                         self.lcfg.latency_scale,
                                         self.lcfg.latency_convention)
             if with_neural and self.neural is not None:

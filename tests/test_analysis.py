@@ -242,3 +242,49 @@ def test_static_graph_catches_the_same_inversion_pattern():
     files = core.collect_files([FIX / "bad_lock_cycle.py"])
     found = [f for f in locks.check(files) if f.rule == "CL002"]
     assert found and "session" in found[0].why and "router" in found[0].why
+
+
+# ---------------------------------------------------------------------------
+# Layer direction: kernels <- core.cascade <- {core.pipeline, core.losses}
+# <- {core.trainer, serving}. Read with ast only, like the rules above; an
+# import inside a function counts as much as one at the top of a module.
+# ---------------------------------------------------------------------------
+
+SRC = REPO / "src"
+
+
+def _imported_modules(path: Path) -> set[str]:
+    """Every module an `import` or `from ... import` in the file names,
+    at any depth: `from a.b import c` yields both a.b and a.b.c."""
+    names = set()
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            names.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            names.add(node.module)
+            names.update(f"{node.module}.{a.name}" for a in node.names)
+    return names
+
+
+# each layer, and the modules it must not import
+LAYER_RULES = {
+    "repro.kernels": ("repro.core", "repro.serving"),
+    "repro.core.cascade": ("repro.core.pipeline", "repro.core.losses",
+                           "repro.serving"),
+    "repro.core.losses": ("repro.core.pipeline", "repro.serving"),
+    "repro.core.pipeline": ("repro.serving",),
+}
+
+
+@pytest.mark.parametrize("layer", LAYER_RULES)
+def test_layers_import_one_way(layer):
+    forbidden = LAYER_RULES[layer]
+    base = SRC.joinpath(*layer.split("."))
+    files = (sorted(base.rglob("*.py")) if base.is_dir()
+             else [base.with_suffix(".py")])
+    assert files
+    bad = sorted(f"{f.relative_to(SRC)} imports {name}"
+                 for f in files for name in _imported_modules(f)
+                 if any(name == m or name.startswith(m + ".")
+                        for m in forbidden))
+    assert not bad, "\n".join(bad)

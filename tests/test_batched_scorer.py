@@ -1,19 +1,17 @@
-"""Parity lockdown for the batched (B, G) cascade scorer (the shared
-serving/training entry point — see kernels/cascade_score/kernel.py).
+"""Parity lockdown for the batched (B, G) cascade scorer (the training
+losses' scoring entry point — see kernels/cascade_score/kernel.py).
 
 Pins four contracts, all in Pallas interpret mode:
-  (a) the batched kernel matches the vmap'd single-group kernel bit for
-      bit on lp, and the batched XLA reference to a few f32 ulp on lp and
-      exactly on the keep counts and survivor masks built from lp, across
-      B/G/d/T grids that are not multiples of the block sizes (and G=1,
-      and all-padded batch rows);
+  (a) the batched kernel matches the XLA reference to a few f32 ulp on
+      lp and exactly on the keep counts and survivor masks built from lp,
+      across B/G/d/T grids that are not multiples of the block sizes (and
+      G=1, and all-padded batch rows);
   (b) the batched backward kernel matches autodiff of the reference
       (<= 1e-5 grad parity through the custom VJP, incl. under vmap/jit);
   (c) the public wrappers reject rank-mismatched inputs with one
       consistent ValueError instead of a pallas_call shape error;
-  (d) run_cascade validates its fused mode up front, and its fused="score"
-      path (now the batched kernel) keeps exact DECISION parity — n_keep
-      and survivor masks — with the fused="none" reference path.
+  (d) run_cascade validates its plan up front, and the training forward
+      scores through the batched op, never through jax.vmap.
 """
 
 import jax
@@ -40,8 +38,8 @@ def _case(b, g, d, t, seed):
 
 
 # ---------------------------------------------------------------------------
-# (a) forward: batched kernel == vmap'd single-group kernel bit for bit;
-# batched kernel ~= XLA ref on lp, == on every discrete decision.
+# (a) forward: batched kernel ~= XLA ref on lp, == on every discrete
+# decision.
 # ---------------------------------------------------------------------------
 
 # lp is a running sum of <= 8 non-positive log-sigmoids, so no cancellation:
@@ -81,12 +79,8 @@ def assert_lp_and_decisions_match_ref(got, ref, seed):
 def test_batched_matches_vmap_and_ref_bitwise(b, g, d, t):
     x, w, zq = _case(b, g, d, t, seed=b * 1009 + g * 13 + d)
     got = np.asarray(cascade_score_batched(x, w, zq, interpret=True))
-    vm = np.asarray(jax.vmap(
-        lambda xb, zb: ops.cascade_score(xb, w, zb, interpret=True))(x, zq))
     ref = np.asarray(cascade_score_batched_ref(x, w, zq))
     assert got.shape == (b, g, t)
-    # bit-for-bit between the two kernel bodies: same ops, same order
-    np.testing.assert_array_equal(got, vm)
     assert_lp_and_decisions_match_ref(got, ref, seed=g)
 
 
@@ -127,7 +121,11 @@ def test_batched_all_padded_rows_are_inert():
 @pytest.mark.parametrize("b,g,d,t", [
     (1, 1, 8, 1), (3, 7, 24, 3),
     pytest.param(2, 130, 40, 5, marks=pytest.mark.slow),
-    pytest.param(4, 64, 24, 3, marks=pytest.mark.slow)])
+    pytest.param(4, 64, 24, 3, marks=pytest.mark.slow),
+    # one group each, the single-group scorer's old sweep: 7 items at
+    # d 5, 64 and 130 at CLOES's d_x, a whole BLOCK_ITEMS tile at
+    # MAX_STAGES
+    (1, 7, 5, 1), (1, 64, 24, 3), (1, 130, 24, 3), (1, 512, 40, 8)])
 def test_batched_backward_kernel_matches_ref_vjp(b, g, d, t):
     x, w, zq = _case(b, g, d, t, seed=b + g + d)
     ct = jnp.asarray(np.random.default_rng(g).normal(size=(b, g, t)),
@@ -143,11 +141,12 @@ def test_batched_backward_kernel_matches_ref_vjp(b, g, d, t):
                                    rtol=1e-4, atol=5e-5)
 
 
-def test_batched_custom_vjp_grads_match_ref_autodiff():
+@pytest.mark.parametrize("b,g,seed", [(3, 48, 11), (1, 50, 1)])
+def test_batched_custom_vjp_grads_match_ref_autodiff(b, g, seed):
     """End-to-end grads through ops.cascade_score_batched with
     interpret=True (Pallas forward AND backward) vs plain autodiff of the
     batched reference — parity <= 1e-5."""
-    x, w, zq = _case(3, 48, 24, 3, seed=11)
+    x, w, zq = _case(b, g, 24, 3, seed=seed)
 
     def loss_pallas(x_, w_, zq_):
         return (ops.cascade_score_batched(x_, w_, zq_,
@@ -163,12 +162,17 @@ def test_batched_custom_vjp_grads_match_ref_autodiff():
                                    rtol=1e-5, atol=1e-5)
 
 
-def test_batched_custom_vjp_under_jit_and_vmap():
+@pytest.mark.parametrize("outer,b", [
+    (2, 3),
+    # one group a minibatch, four minibatches: the interpreter's vmap
+    # compile is the cost here
+    pytest.param(4, 1, marks=pytest.mark.slow)])
+def test_batched_custom_vjp_under_jit_and_vmap(outer, b):
     """The op must stay differentiable when jitted and when vmap'd over an
     outer axis (e.g. an ensemble of minibatches sharing the weights)."""
     rng = np.random.default_rng(4)
-    xs = jnp.asarray(rng.normal(size=(2, 3, 16, 24)), jnp.float32)
-    zs = jnp.asarray(rng.normal(size=(2, 3, 3)), jnp.float32)
+    xs = jnp.asarray(rng.normal(size=(outer, b, 16, 24)), jnp.float32)
+    zs = jnp.asarray(rng.normal(size=(outer, b, 3)), jnp.float32)
     w = jnp.asarray(0.3 * rng.normal(size=(3, 24)), jnp.float32)
 
     def loss(fn, w_):
@@ -194,13 +198,10 @@ def test_wrappers_reject_rank_mismatch_consistently():
     mask = jnp.zeros((2, 4))
     m_q = jnp.zeros((2,))
     cases = [
-        (lambda: ops.cascade_score(x3, w, zq1), "cascade_score:"),
-        (lambda: ops.cascade_score(x2, w, zq2), "cascade_score:"),
         (lambda: ops.cascade_score_batched(x2, w, zq2),
          "cascade_score_batched:"),
         (lambda: ops.cascade_score_batched(x3, w, zq1),
          "cascade_score_batched:"),
-        (lambda: ops.cascade_score_fm(x3, w, zq1), "cascade_score_fm:"),
         (lambda: ops.cascade_filter(x3, w, zq2, mask[0], m_q),
          "cascade_filter:"),
         (lambda: ops.cascade_filter(x2, w, zq2, mask, m_q),
@@ -217,16 +218,17 @@ def test_wrappers_reject_rank_mismatch_consistently():
 
 
 def test_wrapper_rank_check_sees_per_example_shape_under_vmap():
-    """vmap'ing the single-group op over groups (the pre-batched pattern)
-    presents rank-2 per-example tracers — the check must not fire."""
-    x, w, zq = _case(2, 8, 24, 3, seed=1)
-    out = jax.vmap(lambda xb, zb: ops.cascade_score(xb, w, zb,
-                                                    interpret=True))(x, zq)
-    assert out.shape == (2, 8, 3)
+    """vmap'ing the op over an outer axis of minibatches presents rank-3
+    per-example tracers — the check must not fire."""
+    x, w, zq = _case(6, 8, 24, 3, seed=1)
+    out = jax.vmap(lambda xb, zb: ops.cascade_score_batched(
+        xb, w, zb, interpret=True))(x.reshape(2, 3, 8, 24),
+                                    zq.reshape(2, 3, 3))
+    assert out.shape == (2, 3, 8, 3)
 
 
 # ---------------------------------------------------------------------------
-# (d) pipeline integration: up-front mode validation + decision parity.
+# (d) pipeline integration: up-front plan validation; the training forward.
 # ---------------------------------------------------------------------------
 
 def _pipeline_case(seed=0, b=4, g=48):
@@ -242,47 +244,30 @@ def _pipeline_case(seed=0, b=4, g=48):
     return params, cfg, x, q, mask, m_q
 
 
-def test_run_cascade_rejects_unknown_mode_before_computing():
+@pytest.mark.parametrize("plan", ["bogus", "score"])
+def test_run_cascade_rejects_unknown_mode_before_computing(plan):
     """The plan check must fire before w_eff/zq are computed — garbage
-    params that would blow up the scoring setup must not be touched."""
+    params that would blow up the scoring setup must not be touched. The
+    old batched-scorer plan 'score' is gone and fails the same way."""
     _, cfg, x, q, mask, m_q = _pipeline_case()
     bad_params = {"w_x": jnp.zeros((1, 2))}     # would KeyError/shape-error
-    with pytest.raises(ValueError, match="unknown pipeline plan: 'bogus'"):
-        P.run_cascade(bad_params, cfg, x, q, mask, m_q, fused="bogus")
-
-
-def test_run_cascade_score_mode_decision_parity():
-    """fused='score' (batched kernel, interpret) must agree with
-    fused='none' (XLA reference) on every DISCRETE decision: n_keep and
-    the per-stage survivor masks, plus lp bit for bit."""
-    params, cfg, x, q, mask, m_q = _pipeline_case(seed=3)
-    a = P.run_cascade(params, cfg, x, q, mask, m_q, fused="score",
-                      interpret=True)
-    b = P.run_cascade(params, cfg, x, q, mask, m_q, fused="none")
-    np.testing.assert_array_equal(np.asarray(a["lp"]), np.asarray(b["lp"]))
-    np.testing.assert_array_equal(np.asarray(a["n_keep"]),
-                                  np.asarray(b["n_keep"]))
-    np.testing.assert_array_equal(np.asarray(a["survivors"]),
-                                  np.asarray(b["survivors"]))
+    with pytest.raises(ValueError, match=f"unknown pipeline plan: '{plan}'"):
+        P.run_cascade(bad_params, cfg, x, q, mask, m_q, fused=plan)
 
 
 def test_cascade_forward_scores_through_batched_entry_point(monkeypatch):
-    """The trainer's fused forward must resolve its scorer through the
-    pipeline-plan registry (plan "score" -> the batched op) — and never
-    jax.vmap — for both the primal and the penalty-variant scorer."""
-    import dataclasses
+    """The trainer's fused forward must score through the batched op —
+    and never jax.vmap — for both the primal and the penalty-variant
+    scorer."""
     from repro.core import losses as L
     calls = []
-    plan = P.PLANS["score"]
-    assert plan.scorer is ops.cascade_score_batched
-    real = plan.scorer
+    real = ops.cascade_score_batched
 
     def spy(x, w_eff, zq, **kw):
         calls.append(x.shape)
         return real(x, w_eff, zq, **kw)
 
-    monkeypatch.setitem(P.PLANS, "score",
-                        dataclasses.replace(plan, scorer=spy))
+    monkeypatch.setattr(ops, "cascade_score_batched", spy)
 
     def boom(*a, **k):                          # any vmap use is a fail
         raise AssertionError("cascade_forward must not use jax.vmap")

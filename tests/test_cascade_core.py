@@ -9,6 +9,7 @@ import scipy.special
 from repro.core import cascade as C
 from repro.core import losses as L
 from repro.core import metrics as M
+from repro.core import pipeline as P
 from repro.data import features as F
 from repro.data.synthetic import BEHAVIOR_CLICK, BEHAVIOR_NONE, BEHAVIOR_PURCHASE
 
@@ -183,7 +184,7 @@ def test_hard_cascade_respects_thresholds(setup):
     cfg, params, x, q = setup
     mask = jnp.ones(x.shape[:2])
     m_q = jnp.full((4,), 8.0)     # recall == group: counts map 1:1
-    res = C.hard_cascade_filter(params, cfg, x, q, mask, m_q)
+    res = P.run_cascade(params, cfg, x, q, mask, m_q, fused="none")
     kept = np.asarray(res["kept_per_stage"])
     assert (np.diff(kept, axis=-1) <= 0).all()       # monotone filtering
     assert (kept >= 1).all()

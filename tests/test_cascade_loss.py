@@ -3,7 +3,7 @@
 Pins four contracts:
   (a) the fused-loss L3 (the default `losses.loss_l3` path — one
       kernels.ops.cascade_loss_fused call) matches the unfused
-      score-then-reduce graph (pinned through the score_fn seam) in value
+      score-then-reduce graph (`losses.loss_l3_unfused`) in value
       (relative 1e-6) and param grads (1e-5) across the
       cost_mask_positives x latency-convention grid, on raw AND engine
       batches, including fully padded (mask-zero) groups;
@@ -81,14 +81,9 @@ def params(cfg):
 # (a) fused vs unfused L3 — the headline parity contract.
 # ---------------------------------------------------------------------------
 
-def _unfused_l3(params, cfg, lcfg, batch):
-    return L.loss_l3(params, cfg, lcfg, batch,
-                     score_fn=K.cascade_score_batched)
-
-
 def _assert_l3_parity(params, cfg, lcfg, batch, rtol_v=1e-6, rtol_g=1e-5):
     v_f, g_f = jax.value_and_grad(L.loss_l3)(params, cfg, lcfg, batch)
-    v_u, g_u = jax.value_and_grad(_unfused_l3)(params, cfg, lcfg, batch)
+    v_u, g_u = jax.value_and_grad(L.loss_l3_unfused)(params, cfg, lcfg, batch)
     assert np.isfinite(float(v_f))
     assert abs(float(v_f) - float(v_u)) <= rtol_v * max(1.0, abs(float(v_u)))
     for k in g_u:

@@ -52,11 +52,10 @@ def test_smoke_phases_at_tiny_size(monkeypatch, capsys):
     lines = capsys.readouterr().out.strip().splitlines()
     assert json.loads(lines[-1]) == {"ok": True, "device": dev}
     assert checked == ["train step (fused L3 loss)",
-                       "serve plan=filter pipeline (32, 256)",
-                       "serve plan=score pipeline (32, 256)"]
+                       "serve plan=filter pipeline (32, 256)"]
     text = "\n".join(lines)
-    for want in ("compare serve plan=filter", "compare serve plan=score",
-                 "compare loss", "recompiles after warmup 0",
+    for want in ("compare serve plan=filter", "compare loss",
+                 "recompiles after warmup 0",
                  "(per bucket {16: 4, 64: 4, 256: 4})"):
         assert want in text
 

@@ -69,7 +69,7 @@ def _direct(ses, batch, with_neural):
                             fused=ses.scfg.plan)
         surv = out["survivors"][..., -1]
         scores = jnp.where(surv > 0, out["scores"], -jnp.inf)
-        lat = P.latency_from_counts(out["expected_counts"], m_q, cfg,
+        lat = C.latency_from_counts(out["expected_counts"], m_q, cfg,
                                     lcfg.latency_scale,
                                     lcfg.latency_convention)
         if with_neural:

@@ -9,11 +9,11 @@ import pytest
 
 from repro.kernels import ops
 from repro.kernels.cascade_filter.kernel import cascade_filter
-from repro.kernels.cascade_filter.ref import cascade_filter_ref
+from repro.kernels.cascade_filter.ref import cascade_filter_ref, filter_chain
 
 
 # ---------------------------------------------------------------------------
-# cascade_score
+# cascade_score_batched
 # ---------------------------------------------------------------------------
 
 # bfloat16 rows exercise only the kernels' input up-cast on top of the f32
@@ -22,19 +22,36 @@ from repro.kernels.cascade_filter.ref import cascade_filter_ref
 _BF16 = pytest.param(jnp.bfloat16, marks=pytest.mark.slow)
 
 
+def _assert_score_parity(x, w, zq, dtype):
+    got = np.asarray(ops.cascade_score_batched(x, w, zq, interpret=True))
+    want = np.asarray(ops.cascade_score_batched_ref(x, w, zq))
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    assert got.shape == x.shape[:2] + w.shape[:1]
+
+
 @pytest.mark.parametrize("n", [1, 7, 512, 1000, 2048])
 @pytest.mark.parametrize("d,t", [(24, 3), (8, 1), (128, 8), (40, 5)])
 @pytest.mark.parametrize("dtype", [jnp.float32, _BF16])
 def test_cascade_score_sweep(n, d, t, dtype):
+    """One query group of n items."""
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(n * 131 + d), 3)
     x = jax.random.normal(k1, (n, d), dtype)
     w = (0.3 * jax.random.normal(k2, (t, d))).astype(dtype)
     zq = jax.random.normal(k3, (t,), dtype)
-    got = np.asarray(ops.cascade_score(x, w, zq, interpret=True))
-    want = np.asarray(ops.cascade_score_ref(x, w, zq))
-    tol = 1e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
-    assert got.shape == (n, t)
+    _assert_score_parity(x[None], w, zq[None], dtype)
+
+
+@pytest.mark.parametrize("n,d,t", [(1000, 24, 3), (512, 8, 1), (2048, 40, 5)])
+@pytest.mark.parametrize("dtype", [jnp.float32, _BF16])
+def test_cascade_score_multi_block_batch_sweep(n, d, t, dtype):
+    """Four query groups of n items, each one to four item blocks long:
+    the (batch, item-block) grid in both directions at once."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(n + d), 3)
+    x = jax.random.normal(k1, (4, n, d), dtype)
+    w = (0.3 * jax.random.normal(k2, (t, d))).astype(dtype)
+    zq = jax.random.normal(k3, (4, t), dtype)
+    _assert_score_parity(x, w, zq, dtype)
 
 
 def test_cascade_score_cumulative_structure():
@@ -43,7 +60,8 @@ def test_cascade_score_cumulative_structure():
     x = jax.random.normal(k, (256, 24))
     w = 0.3 * jax.random.normal(k, (4, 24))
     zq = jnp.zeros((4,))
-    out = np.asarray(ops.cascade_score(x, w, zq, interpret=True))
+    out = np.asarray(ops.cascade_score_batched(x[None], w, zq[None],
+                                               interpret=True))[0]
     assert (np.diff(out, axis=1) <= 1e-6).all()
 
 
@@ -191,7 +209,6 @@ def test_cascade_filter_select_edges(kind, g):
 def test_select_orders_signed_zeros_as_equal(g):
     """A -0.0 / +0.0 pair at the cut is one tie class, as in the stable
     argsort: the lower index survives whichever sign it carries."""
-    from repro.core.pipeline import filter_chain
     from repro.kernels.cascade_filter.kernel import _select
     rng = np.random.default_rng(g)
     s = -np.abs(rng.normal(size=(1, g))).astype(np.float32) - 1.0
@@ -280,16 +297,3 @@ def test_swa_decode_window_excludes_old_positions():
     v2_ = v.at[:, :cache_len - w].set(-99.0)
     out2 = ops.swa_decode(q, k2_, v2_, cache_len, window=w, interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2), atol=1e-6)
-
-
-@pytest.mark.parametrize("n,d,t", [(1000, 24, 3), (512, 8, 1), (2048, 40, 5)])
-@pytest.mark.parametrize("dtype", [jnp.float32, _BF16])
-def test_cascade_score_feature_major_sweep(n, d, t, dtype):
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(n + d), 3)
-    x = jax.random.normal(k1, (n, d), dtype)
-    w = (0.3 * jax.random.normal(k2, (t, d))).astype(dtype)
-    zq = jax.random.normal(k3, (t,), dtype)
-    got = np.asarray(ops.cascade_score_fm(x.T, w, zq, interpret=True))
-    want = np.asarray(ops.cascade_score_ref(x, w, zq))
-    tol = 1e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)

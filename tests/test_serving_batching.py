@@ -137,7 +137,7 @@ def test_staging_batch_is_one_buffer_in_the_documented_row_layout(g):
 # drains bucket by bucket.
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("fused", ["filter", "score"])
+@pytest.mark.parametrize("fused", ["filter", "none"])
 def test_serve_returns_responses_in_submit_order(fused):
     srv = _server(fused=fused)
     rng = np.random.default_rng(3)
@@ -154,20 +154,14 @@ def test_serve_returns_responses_in_submit_order(fused):
 
 
 def test_server_rejects_unknown_fused_mode_at_construction():
-    # the registry's ONE error — identical across run_cascade, the server,
-    # the session, and the benches
+    # the pipeline's ONE error — identical across run_cascade, the server
+    # and the session
     with pytest.raises(ValueError, match="unknown pipeline plan: 'scores'"):
         _server(fused="scores")
 
 
-def test_serving_bench_rejects_unknown_plan_with_the_same_error():
-    from benchmarks import serving_bench
-    with pytest.raises(ValueError, match="unknown pipeline plan: 'scores'"):
-        serving_bench.run(smoke=True, plan="scores")
-
-
 # ---------------------------------------------------------------------------
-# use_fused_kernel deprecation: one release of aliasing onto the registry.
+# use_fused_kernel deprecation: one release of aliasing onto the plans.
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("legacy, plan", [(True, "filter"), (False, "none")])
@@ -181,10 +175,11 @@ def test_use_fused_kernel_is_deprecated_but_aliases_the_plan(legacy, plan):
     assert srv.fused == plan
     assert srv.session.scfg.plan == plan
     # an explicit fused= wins over the legacy bool (still warns)
+    other = "none" if legacy else "filter"
     with pytest.warns(DeprecationWarning):
         srv2 = CascadeServer(params, cfg, use_fused_kernel=legacy,
-                             fused="score")
-    assert srv2.fused == "score"
+                             fused=other)
+    assert srv2.fused == other
     # the modern spelling is warning-free
     import warnings as _w
     with _w.catch_warnings():

@@ -53,7 +53,7 @@ def _session(params, cfg, *, buckets=(8, 16), batch_groups=4, **kw):
 
 @pytest.mark.parametrize("plan", [
     "filter",
-    pytest.param("score", marks=pytest.mark.slow),  # perf-variant parity
+    pytest.param("none", marks=pytest.mark.slow),  # the XLA reference plan
 ])
 def test_session_flush_bitwise_matches_server_serve(plan):
     params, cfg = _cascade()

@@ -1,12 +1,11 @@
 """Parity suite for the fused training engine (PR 2).
 
-Pins three contracts:
+Pins two contracts (the scorer's custom-VJP backward is pinned in
+test_batched_scorer.py):
   (a) the single-forward losses (core.losses) match the pre-refactor
       multi-forward implementations — kept verbatim below as the oracle —
       in value (<= 1e-6) and grads (<= 1e-5) across the config variants;
-  (b) the fused scorer's custom-VJP backward (Pallas, interpret mode)
-      matches jax.grad of the XLA reference;
-  (c) the scan engine's fit() reproduces the per-step loop's loss
+  (b) the scan engine's fit() reproduces the per-step loop's loss
       trajectory and final params, and evaluate() derives the same metrics
       from its single forward as the old four-pass version.
 """
@@ -22,17 +21,11 @@ from repro.core import cascade as C
 from repro.core import losses as L
 from repro.core import trainer as T
 from repro.data import LogConfig, features as F, generate_log
-from repro.kernels import ops as K
-from repro.kernels.cascade_score.kernel import cascade_score_bwd
-from repro.kernels.cascade_score.ref import cascade_score_ref
 
 
 # ---------------------------------------------------------------------------
 # Pre-refactor reference implementations (the multi-forward originals),
-# kept verbatim as the oracle for the single-forward engine. A sibling
-# copy lives in benchmarks/train_bench.reference_loss_l3 (the bench's
-# loop/scan_donate baseline, which additionally accepts engine batches);
-# a change to the baseline semantics must touch both.
+# kept verbatim as the oracle for the single-forward engine.
 # ---------------------------------------------------------------------------
 
 def ref_weighted_nll(params, cfg, lcfg, x, q, y, mask, behavior=None,
@@ -208,69 +201,7 @@ def test_standalone_term_wrappers_match_reference(setup):
 
 
 # ---------------------------------------------------------------------------
-# (b) custom-VJP backward kernel vs jax.grad of the XLA reference.
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("n,d,t", [(7, 5, 1), (64, 24, 3), (130, 24, 3),
-                                   (512, 40, 8)])
-def test_pallas_backward_kernel_matches_ref_vjp(n, d, t):
-    rng = np.random.default_rng(n + d + t)
-    x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
-    zq = jnp.asarray(rng.normal(size=(t,)), jnp.float32)
-    g = jnp.asarray(rng.normal(size=(n, t)), jnp.float32)
-    _, vjp = jax.vjp(cascade_score_ref, x, w, zq)
-    want = vjp(g)
-    got = cascade_score_bwd(x, w, zq, g, interpret=True)
-    # rtol/atol allow f32 reassociation noise between the kernel's
-    # sum-minus-cumsum reverse cumsum and autodiff's formulation; the
-    # kernel is verified exactly against the closed form in ref.py.
-    for a, b in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=5e-5)
-
-
-def test_custom_vjp_grads_match_ref_autodiff_interpret():
-    """End-to-end grads through ops.cascade_score with interpret=True
-    (Pallas forward AND backward kernels) vs plain autodiff of the ref."""
-    rng = np.random.default_rng(1)
-    x = jnp.asarray(rng.normal(size=(50, 24)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(3, 24)), jnp.float32)
-    zq = jnp.asarray(rng.normal(size=(3,)), jnp.float32)
-
-    def loss_pallas(w_, zq_):
-        return (K.cascade_score(x, w_, zq_, interpret=True) ** 2).sum()
-
-    def loss_ref(w_, zq_):
-        return (cascade_score_ref(x, w_, zq_) ** 2).sum()
-
-    for a, b in zip(jax.grad(loss_pallas, (0, 1))(w, zq),
-                    jax.grad(loss_ref, (0, 1))(w, zq)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.slow           # interpret-mode vmap compile is the cost here
-def test_custom_vjp_supports_vmap_interpret():
-    """The losses vmap the scorer over query groups — the custom VJP must
-    batch on both passes."""
-    rng = np.random.default_rng(2)
-    xb = jnp.asarray(rng.normal(size=(4, 16, 24)), jnp.float32)
-    zb = jnp.asarray(rng.normal(size=(4, 3)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(3, 24)), jnp.float32)
-
-    def loss(fn, w_):
-        return jax.vmap(lambda xx, zz: fn(xx, w_, zz))(xb, zb).sum()
-
-    g_pl = jax.grad(lambda w_: loss(
-        lambda *a: K.cascade_score(*a, interpret=True), w_))(w)
-    g_ref = jax.grad(lambda w_: loss(cascade_score_ref, w_))(w)
-    np.testing.assert_allclose(np.asarray(g_pl), np.asarray(g_ref),
-                               rtol=1e-5, atol=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# (c) scan engine vs loop engine, and the single-forward evaluate().
+# (b) scan engine vs loop engine, and the single-forward evaluate().
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
